@@ -222,6 +222,7 @@ def roofline_records(b=256, c=CLASSES_Q, out_dir=None):
     stage toward the compute roof.
     """
     from repro.launch import mesh as mesh_mod
+    peaks = mesh_mod.device_peaks(mesh_mod.TARGET_DEVICE_KIND)
     out_dir = out_dir or os.path.join(os.path.dirname(__file__), "..",
                                       "experiments", "dryrun")
     os.makedirs(out_dir, exist_ok=True)
@@ -234,8 +235,8 @@ def roofline_records(b=256, c=CLASSES_Q, out_dir=None):
                 ("unfused", 4 * 2 * b * c * 4, b * 4),  # 4 HBM round trips
                 ("fused", 0, 3 * b * 4)):               # kl + 2 lse rows
             bytes_moved = inputs + extra + outputs
-            terms = {"compute_s": flops / mesh_mod.PEAK_FLOPS_BF16,
-                     "memory_s": bytes_moved / mesh_mod.HBM_BW,
+            terms = {"compute_s": flops / peaks.flops_bf16,
+                     "memory_s": bytes_moved / peaks.hbm_bw,
                      "collective_s": 0.0}
             rec = {"arch": f"distill_kl_{variant}",
                    "shape": f"b{b}c{c}_{dtype}", "mesh": "1chip",
@@ -278,6 +279,8 @@ def run(case: str = "all") -> None:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--case", default="all", choices=["all", "quantized"])
     run(ap.parse_args().case)

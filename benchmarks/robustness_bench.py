@@ -139,4 +139,6 @@ def run() -> None:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

@@ -225,6 +225,8 @@ def run(case: str = "all") -> None:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--case", default="all",
                     choices=["all", "engine", "bucketing"])

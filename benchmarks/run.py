@@ -19,6 +19,7 @@ from benchmarks import (distill_bench, fig2_local_epochs,
                         table2_normalization, table3_dropworst,
                         table4_lowbit, table5_init_ablation,
                         table6_local_adam, table7_distill_optimizer)
+from repro.common.compile_cache import use_compile_cache
 
 MODULES = {
     "distill": distill_bench,
@@ -45,6 +46,7 @@ def main(argv=None) -> None:
                     help="comma-separated subset of: " + ",".join(MODULES))
     args = ap.parse_args(argv)
     names = args.only.split(",") if args.only else list(MODULES)
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

@@ -50,4 +50,6 @@ def run(seed: int = 0) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

@@ -24,21 +24,6 @@ def donation_supported() -> bool:
     return jax.default_backend() in ("gpu", "tpu")
 
 
-def shard_map(f, mesh, in_specs, out_specs, check: bool = True):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer releases expose it at the top level with ``check_vma``; 0.4.x only
-    has ``jax.experimental.shard_map`` with ``check_rep``.  ``check`` maps to
-    whichever the installed version takes.
-    """
-    smap = getattr(jax, "shard_map", None)
-    if smap is not None:
-        return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_vma=check)
-    from jax.experimental.shard_map import shard_map as smap_old
-    return smap_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=check)
-
 # Logical axis vocabulary -------------------------------------------------
 #   batch      global batch dimension
 #   seq        sequence dimension of activations

@@ -176,11 +176,10 @@ def make_batched_local_update(net: Net, opt: Optimizer, *,
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
-        from repro.common.sharding import shard_map
         rep, cl = P(), P(client_axis)
-        batched = shard_map(batched, mesh,
-                            in_specs=(rep, cl, cl, rep, cl, cl),
-                            out_specs=cl, check=False)
+        batched = jax.shard_map(batched, mesh=mesh,
+                                in_specs=(rep, cl, cl, rep, cl, cl),
+                                out_specs=cl, check_vma=False)
 
     def counted(params, xb, yb, anchor, step_mask, dp_keys):
         CLIENT_COMPILES.add(1)  # trace-time side effect: counts compiles

@@ -43,6 +43,8 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.common.pytree import (tree_isfinite, tree_leading_dim, tree_stack,
                                  tree_weighted_mean_stacked)
@@ -259,11 +261,22 @@ def _make_distill_opt(fusion: FusionConfig):
     return adam(cosine(fusion.lr, fusion.max_steps))
 
 
+def _kernel_mesh(*trees):
+    """The multi-device mesh a fused-kernel distillation runs on: the
+    first one among its inputs' shardings (a client-sharded round's
+    teacher stack), or None when they sit on one device."""
+    for leaf in jax.tree.leaves(trees):
+        sharding = getattr(leaf, "sharding", None)
+        if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+            return sharding.mesh
+    return None
+
+
 def _build_chunk(student_net: Net, source, fusion: FusionConfig,
                  fused: bool, donate: bool, *, mode: str,
                  teacher_nets: Tuple[Net, ...] = (),
                  teacher_fns: Sequence[Callable] = (),
-                 weighted: bool = False):
+                 weighted: bool = False, mesh=None):
     """One jit'd ``eval_every``-step distillation chunk.
 
     ``mode`` selects what crosses the call boundary as ARGUMENTS (so the
@@ -285,6 +298,11 @@ def _build_chunk(student_net: Net, source, fusion: FusionConfig,
     heterogeneous students share compiled shapes; the padded rows are
     sliced off before the loss, so the update is identical to the
     unpadded one.
+
+    ``mesh`` (fused kernels on multi-device inputs): Mosaic kernels cannot
+    be partitioned automatically, so the chunk runs under ``shard_map``
+    with every operand replicated — each device distils the whole batch,
+    as the automatically partitioned jnp path does.
     """
     opt = _make_distill_opt(fusion)
     if fused:
@@ -377,13 +395,16 @@ def _build_chunk(student_net: Net, source, fusion: FusionConfig,
             length=fusion.eval_every)
         return params, opt_state, key, step
 
+    if mesh is not None:
+        chunk = jax.shard_map(chunk, mesh=mesh, in_specs=P(),
+                              out_specs=P(), check_vma=False)
     return jax.jit(chunk, donate_argnums=(0, 1) if donate else ())
 
 
 def _get_chunk(student_net: Net, teacher_logit_fns: Sequence[Callable],
                source, fusion: FusionConfig, fused: bool,
                bank: Optional[LogitBank], donate: bool,
-               teacher_weights=None):
+               teacher_weights=None, mesh=None):
     """The cross-round cached chunk for this (student, teachers, source,
     fusion) configuration plus its per-call extra arguments.  Cached so
     round t+1's fusion reuses round t's compiled program instead of
@@ -412,7 +433,8 @@ def _get_chunk(student_net: Net, teacher_logit_fns: Sequence[Callable],
         # with zero hits, so keep the historic per-call jit for them
         return _build_chunk(student_net, source, fusion, fused, donate,
                             mode="plain", weighted=weighted,
-                            teacher_fns=tuple(teacher_logit_fns)), w_extra
+                            teacher_fns=tuple(teacher_logit_fns),
+                            mesh=mesh), w_extra
     teacher_nets = (tuple(f.net for f in teacher_logit_fns)
                     if mode == "stacked" else ())
     per = _CHUNK_CACHE.get(student_net)
@@ -420,12 +442,12 @@ def _get_chunk(student_net: Net, teacher_logit_fns: Sequence[Callable],
         per = {}
         _CHUNK_CACHE[student_net] = per
     key = (_fusion_chunk_key(fusion, fused, weighted), mode, id(source),
-           tuple(id(n) for n in teacher_nets), bool(donate))
+           tuple(id(n) for n in teacher_nets), bool(donate), mesh)
     fn = per.get(key)
     if fn is None:
         fn = _build_chunk(student_net, source, fusion, fused, donate,
                           mode=mode, teacher_nets=teacher_nets,
-                          weighted=weighted)
+                          weighted=weighted, mesh=mesh)
         per[key] = fn
     if mode == "bank":
         # scales is None for fp32/bf16 banks — jit treats it as an empty
@@ -542,12 +564,16 @@ def distill(
                                  fusion.batch_size)
 
     donate = donation_supported()
+    mesh = (_kernel_mesh(student_params, bank.logits if bank is not None
+                         else [getattr(f, "stack", None)
+                               for f in teacher_logit_fns])
+            if fused else None)
     # the compiled chunk is cached ACROSS rounds (teacher stacks / bank
     # rows cross the call boundary as arguments): round t+1 reuses round
     # t's program instead of re-jitting a fresh closure per call
     chunk, extra = _get_chunk(student_net, teacher_logit_fns, source,
                               fusion, fused, bank, donate,
-                              teacher_weights=teacher_weights)
+                              teacher_weights=teacher_weights, mesh=mesh)
 
     # the first chunk call donates its params buffer: never donate the
     # caller's — copy once, reuse for 10k steps

@@ -47,6 +47,7 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.engine import _UNSET, RoundEngine
@@ -125,6 +126,14 @@ class DistributedDriver(Driver):
                 "dist.transport='tcp' needs dist.spec_json (run through "
                 "the Experiment/spec API so client pods can rebuild the "
                 "engine)")
+        backend = jax.default_backend()
+        if backend != "cpu":
+            # an accelerator belongs to one process at a time, and this
+            # one already holds it: a child pod would fail or hang
+            raise RuntimeError(
+                f"dist.transport='tcp' starts one JAX process per pod, but "
+                f"this process already holds the {backend} device; use "
+                f"dist.transport='loopback', the one-host transport")
         transport = TCPTransport()
         rt = _Runtime(transport, dcfg.n_pods)
         rt.tmpdir = tempfile.mkdtemp(prefix="repro_dist_")

@@ -44,8 +44,6 @@ from jax import dtypes as jax_dtypes
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG = -1e30
 
 
@@ -98,10 +96,9 @@ def _emit_row_stats(kl_ref, lse_t_ref, lse_s_ref,
                     m_t, z_t, st_acc, ss_acc, m_s, z_s):
     lse_t = m_t[...] + jnp.log(z_t[...])
     lse_s = m_s[...] + jnp.log(z_s[...])
-    kl = (st_acc[...] - ss_acc[...]) / z_t[...] - lse_t + lse_s
-    kl_ref[...] = kl[:, 0]
-    lse_t_ref[...] = lse_t[:, 0]
-    lse_s_ref[...] = lse_s[:, 0]
+    kl_ref[...] = (st_acc[...] - ss_acc[...]) / z_t[...] - lse_t + lse_s
+    lse_t_ref[...] = lse_t
+    lse_s_ref[...] = lse_s
 
 
 def _fwd_kernel(s_ref, t_ref, kl_ref, lse_t_ref, lse_s_ref,
@@ -133,8 +130,8 @@ def _bwd_kernel(s_ref, t_ref, lse_t_ref, lse_s_ref, g_ref, ds_ref, *,
     s = s_ref[...].astype(jnp.float32)
     t = _teacher_tile(t_ref)
     pad = _pad_mask(vi, bv, v_total, s.shape)
-    p_s = jnp.where(pad, 0.0, jnp.exp(s - lse_s_ref[...][:, None]))
-    p_t = jnp.where(pad, 0.0, jnp.exp(t - lse_t_ref[...][:, None]))
+    p_s = jnp.where(pad, 0.0, jnp.exp(s - lse_s_ref[...]))
+    p_t = jnp.where(pad, 0.0, jnp.exp(t - lse_t_ref[...]))
     g = g_ref[0]
     ds_ref[...] = ((p_s - p_t) * (g / b_total)).astype(ds_ref.dtype)
 
@@ -143,14 +140,18 @@ def _bwd_kernel(s_ref, t_ref, lse_t_ref, lse_s_ref, g_ref, ds_ref, *,
 # fused bank kernels: gather-by-index + dequantize + log-softmax + KL
 # ---------------------------------------------------------------------------
 #
-# Grid (B, n_v) with row blocks of 1: the sampled index vector rides in as
-# a SCALAR-PREFETCH operand, so the bank's BlockSpec index map
-# ``lambda i, j, idx_ref: (idx_ref[i], j)`` DMAs exactly the sampled bank
-# row for grid row i — the gathered [B, V] teacher tensor (let alone its
-# dequantized fp32 copy) never exists in HBM.  Quantized rows dequantize
-# in-register: ``t = t_tile * (scale_row / T)``; fp32/bf16 banks pass
-# scale 1.  The student's 1/T fold also happens in-tile (temperature is a
-# static nondiff arg), so there is no [B, V] pre-scaling pass either.
+# Grid (B, n_v), one sampled row per grid row: the sampled index vector
+# rides in as a SCALAR-PREFETCH operand, so the bank's BlockSpec index map
+# ``lambda i, j, idx_ref: (idx_ref[i], 0, j)`` DMAs exactly the sampled
+# bank row for grid row i — the gathered [B, V] teacher tensor (let alone
+# its dequantized fp32 copy) never exists in HBM.  Student and bank are
+# viewed as [rows, 1, V] (a free reshape) so each block's trailing two
+# dims (1, bV) equal the array's (1, V) — the TPU tiling rule a (1, bV)
+# block of an [N, V] array breaks.  The per-row scales sit whole in SMEM.
+# Quantized rows dequantize in-register: ``t = t_tile * (scale_row / T)``;
+# fp32/bf16 banks pass scale 1.  The student's 1/T fold also happens
+# in-tile (temperature is a static nondiff arg), so there is no [B, V]
+# pre-scaling pass either.
 
 def _bank_fwd_kernel(idx_ref, s_ref, t_ref, sc_ref,
                      kl_ref, lse_t_ref, lse_s_ref,
@@ -164,7 +165,7 @@ def _bank_fwd_kernel(idx_ref, s_ref, t_ref, sc_ref,
         _init_row_stats(m_t, z_t, st_acc, ss_acc, m_s, z_s)
 
     s = s_ref[...].astype(jnp.float32) * inv_t          # [1, bV]
-    t = t_ref[...].astype(jnp.float32) * (sc_ref[0] * inv_t)
+    t = t_ref[...].astype(jnp.float32) * (sc_ref[pl.program_id(0)] * inv_t)
 
     pad = _pad_mask(vi, bv, v_total, s.shape)
     s = jnp.where(pad, NEG, s)
@@ -183,10 +184,10 @@ def _bank_bwd_kernel(idx_ref, s_ref, t_ref, sc_ref, lse_t_ref, lse_s_ref,
     del idx_ref
     vi = pl.program_id(1)
     s = s_ref[...].astype(jnp.float32) * inv_t
-    t = t_ref[...].astype(jnp.float32) * (sc_ref[0] * inv_t)
+    t = t_ref[...].astype(jnp.float32) * (sc_ref[pl.program_id(0)] * inv_t)
     pad = _pad_mask(vi, bv, v_total, s.shape)
-    p_s = jnp.where(pad, 0.0, jnp.exp(s - lse_s_ref[...][:, None]))
-    p_t = jnp.where(pad, 0.0, jnp.exp(t - lse_t_ref[...][:, None]))
+    p_s = jnp.where(pad, 0.0, jnp.exp(s - lse_s_ref[...]))
+    p_t = jnp.where(pad, 0.0, jnp.exp(t - lse_t_ref[...]))
     g = g_ref[0]
     ds_ref[...] = ((p_s - p_t) * (g / b_total)).astype(ds_ref.dtype)
 
@@ -225,6 +226,13 @@ def _block_v(v: int) -> int:
     return min(2048, max(128, 128 * ((v + 127) // 128)))
 
 
+def _row_spec(bb: int):
+    """Per-row statistics ([rows, 1] fp32) by B tile: a 2-D block, since
+    the TPU refuses rank-1 blocks that are neither the whole array nor a
+    multiple of 128."""
+    return pl.BlockSpec((bb, 1), lambda i, j: (i, 0))
+
+
 def _pad_teacher(t, bb, bv):
     """Pad [B, V] (pre-averaged) or [K, B, V] teachers + their BlockSpec."""
     if t.ndim == 2:
@@ -248,7 +256,6 @@ def _fwd(student_logits, teacher_logits, temperature, block_b, interpret):
     n_b, n_v = bp // bb, vp // bv
 
     kern = functools.partial(_fwd_kernel, n_v_tiles=n_v, v_total=v, bv=bv)
-    out_shape = [jax.ShapeDtypeStruct((bp,), jnp.float32)] * 3
     kl, lse_t, lse_s = pl.pallas_call(
         kern,
         grid=(n_b, n_v),
@@ -256,14 +263,10 @@ def _fwd(student_logits, teacher_logits, temperature, block_b, interpret):
             pl.BlockSpec((bb, bv), lambda i, j: (i, j)),
             t_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-        ],
+        out_specs=[_row_spec(bb)] * 3,
         scratch_shapes=[pltpu.VMEM((bb, 1), jnp.float32)] * 6,
-        out_shape=out_shape,
-        compiler_params=CompilerParams(
+        out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(s_p, t_p)
@@ -298,13 +301,13 @@ def _bwd_rule(temperature, block_b, interpret, res, g):
         in_specs=[
             pl.BlockSpec((bb, bv), lambda i, j: (i, j)),
             t_spec,
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
-            pl.BlockSpec((bb,), lambda i, j: (i,)),
+            _row_spec(bb),
+            _row_spec(bb),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bb, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp, vp), student_logits.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(s_p, t_p, lse_t, lse_s, g_arr)
@@ -341,42 +344,64 @@ def ensemble_kl_bank(student_logits, bank_rows, row_scale, idx,
     return loss
 
 
-def _bank_specs(b: int, n_v: int, bv: int):
-    """(grid, in_specs) shared by the bank fwd/bwd: student row blocks by
-    grid row, bank row blocks by the PREFETCHED sampled index."""
-    grid = (b, n_v)
+def _bank_block_v(v: int) -> int:
+    """V tile of the bank kernels.  The bank is never padded (that would
+    copy it), so a tile is either the whole row or 2048 lanes with a
+    masked partial tail tile."""
+    return v if v <= 2048 else 2048
+
+
+_SQ = pl.Squeezed()
+
+
+def _bank_call(kernel, b: int, n_v: int, bv: int, extra_in_specs, out_specs,
+               out_shape, scratch_shapes, interpret: bool):
+    """pallas_call shared by the bank fwd/bwd: grid (B, n_v); student row
+    blocks by grid row, bank row blocks by the PREFETCHED sampled index,
+    per-row scales whole in SMEM."""
     in_specs = [
-        pl.BlockSpec((1, bv), lambda i, j, idx_ref: (i, j)),
-        pl.BlockSpec((1, bv), lambda i, j, idx_ref: (idx_ref[i], j)),
-        pl.BlockSpec((1,), lambda i, j, idx_ref: (i,)),
-    ]
-    return grid, in_specs
+        pl.BlockSpec((_SQ, 1, bv), lambda i, j, idx_ref: (i, 0, j)),
+        pl.BlockSpec((_SQ, 1, bv), lambda i, j, idx_ref: (idx_ref[i], 0, j)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+    ] + list(extra_in_specs)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_v),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )
+
+
+_BANK_ROW_SPEC = pl.BlockSpec((_SQ, 1, 1), lambda i, j, idx_ref: (i, 0, 0))
+
+
+def _rows3(x):
+    """[R, V] -> the [R, 1, V] view the bank kernels block over."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
 
 
 def _bank_fwd(student_logits, bank_rows, row_scale, idx, temperature,
               interpret):
     b, v = student_logits.shape
-    bv = _block_v(v)
+    bv = _bank_block_v(v)
     n_v = -(-v // bv)
-
-    grid, in_specs = _bank_specs(b, n_v, bv)
     kern = functools.partial(_bank_fwd_kernel, n_v_tiles=n_v, v_total=v,
                              bv=bv, inv_t=1.0 / temperature)
-    row_spec = pl.BlockSpec((1,), lambda i, j, idx_ref: (i,))
-    kl, lse_t, lse_s = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=[row_spec, row_spec, row_spec],
-            scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)] * 6,
-        ),
-        out_shape=[jax.ShapeDtypeStruct((b,), jnp.float32)] * 3,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+    kl, lse_t, lse_s = _bank_call(
+        kern, b, n_v, bv, (),
+        out_specs=[_BANK_ROW_SPEC] * 3,
+        out_shape=[jax.ShapeDtypeStruct((b, 1, 1), jnp.float32)] * 3,
+        scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)] * 6,
         interpret=interpret,
-    )(idx.astype(jnp.int32), student_logits, bank_rows,
+    )(idx.astype(jnp.int32), _rows3(student_logits), _rows3(bank_rows),
       row_scale.astype(jnp.float32))
     loss = jnp.sum(kl) / b * temperature ** 2
     return loss, (student_logits, bank_rows, row_scale, idx, lse_t, lse_s)
@@ -391,31 +416,24 @@ def _bank_fwd_rule(student_logits, bank_rows, row_scale, idx, temperature,
 def _bank_bwd_rule(temperature, interpret, res, g):
     student_logits, bank_rows, row_scale, idx, lse_t, lse_s = res
     b, v = student_logits.shape
-    bv = _block_v(v)
+    bv = _bank_block_v(v)
     n_v = -(-v // bv)
-
-    grid, in_specs = _bank_specs(b, n_v, bv)
-    row_spec = pl.BlockSpec((1,), lambda i, j, idx_ref: (i,))
-    in_specs = in_specs + [row_spec, row_spec,
-                           pl.BlockSpec(memory_space=pltpu.SMEM)]
     kern = functools.partial(_bank_bwd_kernel, v_total=v, bv=bv, b_total=b,
                              inv_t=1.0 / temperature)
     g_arr = jnp.asarray([g * temperature], jnp.float32)  # T^2 / T = T
-    ds = pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bv), lambda i, j, idx_ref: (i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, n_v * bv), student_logits.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+    ds = _bank_call(
+        kern, b, n_v, bv,
+        (_BANK_ROW_SPEC, _BANK_ROW_SPEC,
+         pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_specs=pl.BlockSpec((_SQ, 1, bv),
+                               lambda i, j, idx_ref: (i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_v * bv),
+                                       student_logits.dtype),
+        scratch_shapes=(),
         interpret=interpret,
-    )(idx.astype(jnp.int32), student_logits, bank_rows,
+    )(idx.astype(jnp.int32), _rows3(student_logits), _rows3(bank_rows),
       row_scale.astype(jnp.float32), lse_t, lse_s, g_arr)
-    return (ds[:, :v], _zero_cotangent(bank_rows),
+    return (ds[:, 0, :v], _zero_cotangent(bank_rows),
             _zero_cotangent(row_scale), _zero_cotangent(idx))
 
 

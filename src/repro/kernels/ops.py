@@ -1,12 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU
-they compile by default.  ``REPRO_PALLAS_COMPILE=1``/``0`` forces either
-mode on any backend.
+On a TPU the kernels always compile; on any other backend they run in
+interpret mode (which exists for testing, not speed).
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -20,12 +18,8 @@ from repro.kernels.swa_attn import swa_attn_pallas as _swa
 
 
 def _interpret() -> bool:
-    """Interpret-mode default: compiled on TPU (so ``use_fused_kernel=
-    'auto'`` actually lands on the fast kernel), interpret elsewhere.
-    ``REPRO_PALLAS_COMPILE=1``/``0`` overrides either way."""
-    env = os.environ.get("REPRO_PALLAS_COMPILE")
-    if env is not None:
-        return env != "1"
+    """True only off TPU: on a TPU the kernels always compile, so
+    ``use_fused_kernel='auto'`` lands on the compiled kernel."""
     return jax.default_backend() != "tpu"
 
 
@@ -79,8 +73,8 @@ def ensemble_kl_loss_bank(student_logits: jax.Array, bank_rows: jax.Array,
     (fp32 / bf16 / int8 / fp8); scales: per-ROW [N] fp32 dequant scales
     or None for unquantized banks; idx: [...] sampled bank indices.
     Dispatches exactly like :func:`ensemble_kl_loss_pre` (compiled on
-    TPU, interpret elsewhere, ``REPRO_PALLAS_COMPILE`` override) — only
-    the [B]-sized per-sample scale gather happens outside the kernel.
+    TPU, interpret elsewhere) — only the [B]-sized per-sample scale
+    gather happens outside the kernel.
     """
     v = student_logits.shape[-1]
     s2 = student_logits.reshape(-1, v)

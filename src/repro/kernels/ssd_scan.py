@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state, *,
                 s_total: int, q: int):
@@ -107,7 +105,7 @@ def ssd_scan_pallas(x, dt, a_log, bmat, cmat, chunk: int = 128,
                                lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nc, q, hp, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((bh, n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xc, dtc, a_log, bc, cc)

@@ -165,9 +165,10 @@ def roofline(cfg, shape, mesh, cost, coll_total_per_dev) -> dict:
     chips = mesh.devices.size
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
-    compute_t = flops_dev / mesh_mod.PEAK_FLOPS_BF16
-    memory_t = bytes_dev / mesh_mod.HBM_BW
-    collective_t = coll_total_per_dev / mesh_mod.ICI_BW
+    peaks = mesh_mod.device_peaks(mesh_mod.TARGET_DEVICE_KIND)
+    compute_t = flops_dev / peaks.flops_bf16
+    memory_t = bytes_dev / peaks.hbm_bw
+    collective_t = coll_total_per_dev / peaks.ici_bw
     terms = {"compute_s": compute_t, "memory_s": memory_t,
              "collective_s": collective_t}
     dominant = max(terms, key=terms.get)

@@ -44,6 +44,7 @@ from repro.api import (BucketSpec, CohortSpec, DistSpec, DriverSpec,
                        PrivacySpec, ShardingSpec, SourceSpec, StrategySpec,
                        TaskSpec, TrafficSpec, default_prototype_ladder)
 from repro.checkpoint import io as ckpt
+from repro.common.compile_cache import use_compile_cache
 from repro.common.options import (ARRIVAL_KINDS, BANK_DTYPES, BUCKET_KINDS,
                                   BYZANTINE_MODES, SCREEN_MODES,
                                   TRANSPORT_KINDS)
@@ -384,6 +385,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.profile and not args.profile_dir:
         args.profile_dir = os.path.join(args.out, "profile")
+    use_compile_cache()
 
     t0 = time.time()
     if args.resume:

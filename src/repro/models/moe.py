@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.common.arch_config import ArchConfig
-from repro.common.sharding import shard_map
 from repro.models.layers import ParamSpec
 
 
@@ -158,6 +157,6 @@ def _moe_shard_map(p: dict, cfg: ArchConfig, x2: jax.Array, mesh, dp):
         P(dp_spec, None),              # tokens over data axes
     )
     out_specs = (P(dp_spec, None), P())
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["wi_gate"], p["wi_up"], p["wo"], x2)

@@ -26,9 +26,9 @@ thread until popped.
 Optional jax-profiler passthrough: when armed with ``profile_dir`` the
 recorder calls ``jax.profiler.start_trace`` and enters a
 ``TraceAnnotation(name)`` alongside each span, so the same span
-taxonomy shows up on XLA timelines.  jax is imported lazily and every
-profiler call is guarded — a build without profiler support degrades to
-plain JSONL tracing.
+taxonomy shows up on XLA timelines.  jax is imported lazily.  A profiler
+that was asked for and cannot start raises: a profiled run that wrote no
+trace would read as one that had nothing to trace.
 """
 from __future__ import annotations
 
@@ -152,13 +152,10 @@ class FlightRecorder:
     def _start_profiler(self) -> None:
         if not self.profile_dir:
             return
-        try:
-            import jax
-            os.makedirs(self.profile_dir, exist_ok=True)
-            jax.profiler.start_trace(self.profile_dir)
-            self._profiling = True
-        except Exception:  # pragma: no cover - no profiler support
-            self._profiling = False
+        import jax
+        os.makedirs(self.profile_dir, exist_ok=True)
+        jax.profiler.start_trace(self.profile_dir)
+        self._profiling = True
 
     def _stop_profiler(self) -> None:
         if not self._profiling:
@@ -227,13 +224,19 @@ class FlightRecorder:
 
 def arm(path: Optional[str] = None, profile_dir: Optional[str] = None
         ) -> FlightRecorder:
-    """Install (and return) a recorder; replaces any armed one."""
+    """Install (and return) a recorder; replaces any armed one.  Raises,
+    leaving none armed, when ``profile_dir`` is set and the jax profiler
+    cannot start."""
     global _RECORDER
-    if _RECORDER is not None:
-        _RECORDER.close()
-    _RECORDER = FlightRecorder(path=path, profile_dir=profile_dir)
-    _RECORDER._start_profiler()
-    return _RECORDER
+    disarm()
+    rec = FlightRecorder(path=path, profile_dir=profile_dir)
+    try:
+        rec._start_profiler()
+    except BaseException:
+        rec.close()
+        raise
+    _RECORDER = rec
+    return rec
 
 
 def disarm() -> None:

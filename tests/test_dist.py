@@ -545,3 +545,15 @@ def test_tcp_transport_end_to_end():
     for x, y in zip(jax.tree.leaves(ref.global_params[0]),
                     jax.tree.leaves(got.global_params[0])):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_tcp_transport_refused_when_process_holds_an_accelerator(
+        monkeypatch):
+    """An accelerator belongs to one process: with the parent on a TPU the
+    tcp transport must refuse before starting any pod, naming the
+    one-host transport."""
+    from repro.dist.driver import DistributedDriver
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dcfg = DistConfig(transport="tcp", spec_json="{}")
+    with pytest.raises(RuntimeError, match="loopback"):
+        DistributedDriver()._start_pods(None, dcfg)

@@ -594,6 +594,58 @@ print("SHARDED_BANK_OK", sharded.n_teacher_batch_forwards)
     assert r.stdout.count("SHARDED_BANK_OK") == 1, r.stdout + r.stderr
 
 
+def test_fused_distill_over_sharded_bank_matches_one_device():
+    """A bank spread over a 4-device mesh runs the fused-kernel distill
+    chunk replicated under shard_map (Mosaic kernels cannot be
+    partitioned automatically); the student it distils equals the one
+    distilled from the same bank on one device."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, {src!r})
+import jax
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.common.pytree import tree_stack
+from repro.core import mlp
+from repro.core.feddf import FusionConfig, distill, make_teacher_logits_fn
+from repro.core.logit_bank import build_logit_bank
+from repro.data import UnlabeledDataset
+from repro.launch.mesh import make_client_mesh
+
+net = mlp(4, 5, hidden=(16,))
+stack = tree_stack([net.init(jax.random.PRNGKey(i)) for i in range(3)])
+tfn = make_teacher_logits_fn(net, stack)
+pool = np.random.default_rng(0).uniform(-3, 3, (512, 4)).astype(np.float32)
+source = UnlabeledDataset(pool)
+fusion = FusionConfig(max_steps=40, patience=40, eval_every=20,
+                      batch_size=32, use_fused_kernel=True)
+student = net.init(jax.random.PRNGKey(9))
+sharding = NamedSharding(make_client_mesh(4), P("data"))
+outs = []
+for bank in (build_logit_bank([tfn], pool),
+             build_logit_bank([tfn], pool, sharding=sharding)):
+    params, info = distill(net, student, [tfn], source, fusion, bank=bank)
+    assert info["steps"] == 40, info
+    outs.append(params)
+leaf = jax.tree.leaves(outs[1])[0]
+assert len(leaf.sharding.device_set) == 4, leaf.sharding
+for a, b in zip(*map(jax.tree.leaves, outs)):
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               rtol=1e-6, atol=1e-7)
+print("SHARDED_FUSED_OK")
+""".format(src=os.path.join(root, "src"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True)
+    assert r.stdout.count("SHARDED_FUSED_OK") == 1, r.stdout + r.stderr
+
+
 # ---------------------------------------------------------------------------
 # quantized banks (int8 / fp8_e4m3 rows + per-row fp32 scales)
 # ---------------------------------------------------------------------------

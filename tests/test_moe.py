@@ -90,12 +90,13 @@ from repro import configs
 from repro.common.arch_config import reduced
 from repro.models import moe as moe_mod
 from repro.models.layers import init_params
+from repro.launch.mesh import make_debug_mesh
 
 cfg = dataclasses.replace(reduced(configs.get("granite-moe-1b-a400m")),
                           capacity_factor=64.0)
 p = init_params(moe_mod.moe_specs(cfg), jax.random.PRNGKey(0))
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 
 local, aux_l = moe_mod.moe_block(p, cfg, x, mesh=None)
 dist, aux_d = moe_mod.moe_block(p, cfg, x, mesh=mesh, dp_axes=("data",))

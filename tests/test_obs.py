@@ -126,6 +126,20 @@ def test_rearm_closes_previous_recorder(tmp_path):
     assert len(trace.load_spans(str(tmp_path / "b.jsonl"))) == 1
 
 
+def test_profiler_start_failure_raises_and_arms_nothing(tmp_path,
+                                                         monkeypatch):
+    import jax
+
+    def refuse(log_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        trace.arm(path=str(tmp_path / "s.jsonl"),
+                  profile_dir=str(tmp_path / "prof"))
+    assert trace.recorder() is None
+
+
 # ---------------------------------------------------------------------------
 # metrics registry + sinks
 # ---------------------------------------------------------------------------

@@ -19,8 +19,9 @@ import jax
 from repro import configs
 from repro.common.arch_config import reduced
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_debug_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 shape = dataclasses.replace(configs.get_shape("train_4k"), seq_len=32,
                             global_batch=8)
 pshape = dataclasses.replace(configs.get_shape("prefill_32k"), seq_len=64,
@@ -60,8 +61,9 @@ import numpy as np
 from repro import configs
 from repro.common.arch_config import reduced
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_debug_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 shape = dataclasses.replace(configs.get_shape("train_4k"), seq_len=16,
                             global_batch=8)
 cfg = reduced(configs.get("qwen3-8b"))

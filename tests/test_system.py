@@ -68,9 +68,10 @@ import jax
 from repro import configs
 from repro.common.arch_config import reduced
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_debug_mesh
 import dataclasses
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 shape = dataclasses.replace(configs.get_shape("train_4k"), seq_len=32,
                             global_batch=4)
 for arch in ("qwen3-8b", "granite-moe-1b-a400m", "zamba2-1.2b"):
