@@ -1,0 +1,366 @@
+"""Plain reference of the first FedDF / FedAvg round, in ``jax.numpy``.
+
+It imports nothing of the program and takes nothing the program made: it
+draws its own weights from the seed, trains its own clients, builds its
+own logit bank and distils its own student, from the same inputs and by
+the same published equations (the paper's Algorithms 1-3, the model's
+layer equations, Adam, the cosine schedule).  It runs in float32 at
+``highest`` matmul precision; ``dtype="bfloat16"`` gives the lower-
+precision control, which runs the same code with parameters, optimizer
+state and activations in bfloat16.
+
+It runs one client, one 512-row block or one step at a time, so that it
+fits beside nothing else on the chip once the program's state is freed.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+import time
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+EVAL_BLOCK = 512
+ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
+
+
+# -- the model: a pre-norm transformer encoder classifier ---------------------
+
+def init_params(key, model: dict, dtype) -> dict:
+    """Weights from ``key``: normal draws scaled by 1/sqrt(fan-in), in the
+    order one split of ``key`` hands them out."""
+    d, n_layers = int(model["d_model"]), int(model["n_layers"])
+    vocab, seq, n_cls = (int(model["vocab_size"]), int(model["seq_len"]),
+                         int(model["n_classes"]))
+    ks = jax.random.split(key, 3 + 4 * n_layers)
+    nrm = jax.random.normal
+    p = {"embed": nrm(ks[0], (vocab, d)) * 0.05,
+         "pos": nrm(ks[1], (seq, d)) * 0.05,
+         "head": {"w": nrm(ks[2], (d, n_cls)) * (1.0 / math.sqrt(d)),
+                  "b": jnp.zeros((n_cls,))}}
+    for l in range(n_layers):
+        k = ks[3 + 4 * l:7 + 4 * l]
+        s = 1.0 / math.sqrt(d)
+        p[f"layer_{l}"] = {
+            "wqkv": nrm(k[0], (d, 3 * d)) * s,
+            "wo": nrm(k[1], (d, d)) * s,
+            "w1": nrm(k[2], (d, 4 * d)) * s,
+            "w2": nrm(k[3], (4 * d, d)) * (1.0 / math.sqrt(4 * d)),
+            "ln1": jnp.ones((d,)), "ln2": jnp.ones((d,))}
+    return jax.tree.map(lambda a: a.astype(dtype), p)
+
+
+def _rms(w, x):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
+
+
+def forward(params: dict, x, model: dict):
+    """Logits [B, C]: token + position embedding, then per layer
+    h += Wo attn(RMS(h)); h += W2 gelu(W1 RMS(h)); mean-pool; linear head."""
+    n_heads, n_layers = int(model["n_heads"]), int(model["n_layers"])
+    b, s = x.shape
+    h = params["embed"][x] + params["pos"][None, :s]
+    d = h.shape[-1]
+    hd = d // n_heads
+    for l in range(n_layers):
+        p = params[f"layer_{l}"]
+        y = _rms(p["ln1"], h)
+        q, k, v = jnp.split(y @ p["wqkv"], 3, axis=-1)
+        q, k, v = (a.reshape(b, s, n_heads, hd) for a in (q, k, v))
+        att = jax.nn.softmax(
+            jnp.einsum("bshd,bthd->bhst", q, k) / math.sqrt(hd), axis=-1)
+        h = h + jnp.einsum("bhst,bthd->bshd", att, v).reshape(b, s, d) \
+            @ p["wo"]
+        h = h + jax.nn.gelu(_rms(p["ln2"], h) @ p["w1"]) @ p["w2"]
+    return jnp.mean(h, axis=1) @ params["head"]["w"] + params["head"]["b"]
+
+
+# -- Adam (Kingma & Ba), as the paper trains clients and the student ----------
+
+def _adam(params, m, v, grads, step, lr, cast):
+    """One Adam step with bias correction; ``step`` counts from 0 and
+    ``cast`` puts a float32 scalar into the working precision."""
+    t = step + 1.0
+    m = jax.tree.map(lambda a, g: ADAM_B1 * a + (1 - ADAM_B1) * g, m, grads)
+    v = jax.tree.map(lambda a, g: ADAM_B2 * a + (1 - ADAM_B2) * g * g, v,
+                     grads)
+    mh, vh = cast(1.0 - ADAM_B1 ** t), cast(1.0 - ADAM_B2 ** t)
+    params = jax.tree.map(
+        lambda p, a, b: (p - lr * (a / mh) / (jnp.sqrt(b / vh) + ADAM_EPS)
+                         ).astype(p.dtype), params, m, v)
+    return params, m, v
+
+
+def cosine_lr(lr: float, total: int, step):
+    t = jnp.clip(step / max(total, 1), 0.0, 1.0)
+    return lr * 0.5 * (1.0 + jnp.cos(jnp.pi * t))
+
+
+class Model:
+    """The jitted pieces of one configuration at one precision: float32 at
+    ``highest`` matmul precision, or the bfloat16 control."""
+
+    def __init__(self, model: dict, job: dict, dtype=jnp.float32):
+        self.model, self.dtype = model, jnp.dtype(dtype)
+        prec = "highest" if self.dtype == jnp.float32 else "default"
+        local_lr = float(job["local_lr"])
+        distill_lr = float(job.get("distill_lr", 1e-3))
+        steps = int(job.get("distill_steps", 1))
+        temp = float(job.get("temperature", 1.0))
+        batch = int(job.get("distill_batch", 1))
+        cast = lambda a: jnp.asarray(a, jnp.float32).astype(self.dtype)
+
+        def fwd(params, x):
+            with jax.default_matmul_precision(prec):
+                return forward(params, x, model)
+
+        def xent(params, x, y):
+            logp = jax.nn.log_softmax(fwd(params, x), axis=-1)
+            return -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
+
+        def client_step(params, m, v, x, y, step):
+            grads = jax.grad(xent)(params, x, y)
+            norms = jax.tree.map(lambda g: jnp.sqrt(jnp.sum(jnp.square(
+                g.astype(jnp.float32)))), grads)
+            params, m, v = _adam(params, m, v, grads, step, cast(local_lr),
+                                 cast)
+            return params, m, v, norms
+
+        def kl(params, x, t_rows):
+            logp_t = jax.nn.log_softmax(t_rows.astype(self.dtype) / temp, -1)
+            logp_s = jax.nn.log_softmax(fwd(params, x) / temp, -1)
+            return jnp.mean(jnp.sum(jnp.exp(logp_t) * (logp_t - logp_s),
+                                    -1)) * temp ** 2
+
+        def distill_step(params, m, v, key, step, pool, bank):
+            key, k1 = jax.random.split(key)
+            idx = jax.random.randint(k1, (batch,), 0, pool.shape[0])
+            grads = jax.grad(kl)(params, pool[idx], bank[idx])
+            params, m, v = _adam(params, m, v, grads, step,
+                                 cast(cosine_lr(distill_lr, steps, step)),
+                                 cast)
+            return params, m, v, key
+
+        self.client_step = jax.jit(client_step)
+        self.distill_step = jax.jit(distill_step)
+        self.logits = jax.jit(lambda p, x: fwd(p, x).astype(jnp.float32))
+
+    def init(self, seed: int) -> dict:
+        return init_params(jax.random.PRNGKey(seed), self.model, self.dtype)
+
+    def predict(self, params, x: np.ndarray) -> np.ndarray:
+        """Logits [n, C] in float32, 512 rows at a time (the last block
+        padded, so that one program serves every block)."""
+        out = []
+        for s in range(0, len(x), EVAL_BLOCK):
+            xb = x[s:s + EVAL_BLOCK]
+            pad = EVAL_BLOCK - len(xb)
+            if pad:
+                xb = np.concatenate([xb, np.zeros((pad,) + xb.shape[1:],
+                                                  xb.dtype)])
+            out.append(np.asarray(self.logits(params, jnp.asarray(xb)))
+                       [:EVAL_BLOCK - pad])
+        return np.concatenate(out)
+
+    def accuracy(self, params, x: np.ndarray, y: np.ndarray) -> float:
+        return float(np.mean(np.argmax(self.predict(params, x), -1) == y))
+
+
+# -- the round ----------------------------------------------------------------
+
+def client_batches(x: np.ndarray, y: np.ndarray, batch: int, epochs: int,
+                   seed: int):
+    """A client's local steps: per epoch one permutation of its rows cut
+    into whole batches (one resampled batch when it has fewer rows)."""
+    rng = np.random.default_rng(seed)
+    n = len(y)
+    per_epoch = max(1, n // batch)
+    for _ in range(epochs):
+        order = (rng.permutation(n)[:per_epoch * batch] if n >= batch
+                 else rng.choice(n, size=batch, replace=True))
+        for s in range(per_epoch):
+            ix = order[s * batch:(s + 1) * batch]
+            yield x[ix], y[ix]
+
+
+def weighted_mean(trees: List[dict], weights) -> dict:
+    w = np.asarray(weights, np.float64)
+    w = jnp.asarray(w / w.sum(), jnp.float32)
+    return jax.tree.map(
+        lambda *xs: sum(wi * a.astype(jnp.float32) for wi, a in zip(w, xs)
+                        ).astype(xs[0].dtype), *trees)
+
+
+def leaf_norms(tree: dict, base: Optional[dict] = None) -> Dict[str, float]:
+    """Per-leaf L2 norm of ``tree`` (minus ``base``), keyed by leaf path."""
+    out = {}
+    base_leaves = (jax.tree_util.tree_leaves_with_path(base)
+                   if base is not None else None)
+    for i, (path, a) in enumerate(jax.tree_util.tree_leaves_with_path(tree)):
+        a = a.astype(jnp.float32)
+        if base_leaves is not None:
+            a = a - base_leaves[i][1].astype(jnp.float32)
+        out[jax.tree_util.keystr(path)] = float(jnp.sqrt(jnp.sum(a * a)))
+    return out
+
+
+@dataclasses.dataclass
+class RoundOne:
+    """What round 1 produced, in the terms the comparison reads."""
+    clients: List[List[Dict[str, float]]]  # per group, per client: |change|
+    first_grad: Dict[str, float]           # first client's first gradient
+    bank: Optional[np.ndarray]             # [n_pool, C] averaged logits
+    fused: List[Dict[str, float]]          # per group: |fused - init|
+    chunks: List[Dict[str, Dict[str, float]]]  # per distillation: its
+    #   first eval_every steps' |change| and Adam's gradient norm per leaf
+    distilled: List[bool]                  # per group: went through distill
+    test_acc: List[float]
+    val_acc: List[float]
+    pre_acc: List[Optional[float]]
+    ens_acc: Optional[float]
+    seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
+    val_history: List[list] = dataclasses.field(default_factory=list)
+
+
+def round_one(models: List[Model], job: dict, inputs, proto: List[int],
+              seed: int, heterogeneous: bool) -> RoundOne:
+    """Algorithm 1 (one group) or Algorithm 3 (several) for round t=1:
+    the cohort draw, each client's local Adam steps from the group's
+    initial global, the data-weighted mean, and for FedDF the teacher
+    bank over the pool and each student's distillation with its
+    best-on-validation checkpoint."""
+    clock = [time.perf_counter()]
+    seconds: Dict[str, float] = {}
+
+    def lap(name):  # every phase ends in host values, so the clock is fair
+        now = time.perf_counter()
+        seconds[name] = round(now - clock[0], 3)
+        clock[0] = now
+
+    t = 1
+    n_clients = len(inputs.parts)
+    n_active = max(1, int(round(float(job["client_fraction"]) * n_clients)))
+    active = np.random.default_rng(seed).choice(n_clients, size=n_active,
+                                                replace=False)
+    mult = 99991 if heterogeneous else 100_003
+    g0 = [m.init(seed + p if heterogeneous else seed)
+          for p, m in enumerate(models)]
+    feddf = job["strategy"] == "feddf"
+    batch, epochs = int(job["local_batch_size"]), int(job["local_epochs"])
+
+    clients, first_grad = [], None
+    stacks: List[List[dict]] = [[] for _ in models]
+    weights: List[List[float]] = [[] for _ in models]
+    for p, m in enumerate(models):
+        rows = []
+        for k in [int(k) for k in active if proto[int(k)] == p]:
+            part = inputs.parts[k]
+            params = g0[p]
+            mom = jax.tree.map(jnp.zeros_like, params)
+            vel = jax.tree.map(jnp.zeros_like, params)
+            for i, (xb, yb) in enumerate(client_batches(
+                    inputs.train.x[part], inputs.train.y[part], batch,
+                    epochs, seed * mult + t * 131 + k)):
+                params, mom, vel, norms = m.client_step(
+                    params, mom, vel, jnp.asarray(xb), jnp.asarray(yb),
+                    jnp.float32(i))
+                if first_grad is None:
+                    first_grad = {
+                        jax.tree_util.keystr(path): float(g) for path, g in
+                        jax.tree_util.tree_leaves_with_path(norms)}
+            rows.append(leaf_norms(params, g0[p]))
+            stacks[p].append(params)
+            weights[p].append(float(len(part)))
+            del mom, vel
+        clients.append(rows)
+    lap("clients")
+
+    ens_acc = None
+    if heterogeneous:
+        tot = None
+        for p, m in enumerate(models):
+            for params in stacks[p]:
+                lg = m.predict(params, inputs.test.x)
+                tot = lg if tot is None else tot + lg
+        ens_acc = float(np.mean(np.argmax(tot, -1) == inputs.test.y))
+
+    bank = None
+    if feddf:
+        pool = inputs.pool
+        bank = np.zeros((len(pool), int(models[0].model["n_classes"])),
+                        np.float64)
+        n_teach = 0
+        for p, m in enumerate(models):
+            for params in stacks[p]:
+                bank += m.predict(params, pool)
+                n_teach += 1
+        bank = (bank / n_teach).astype(np.float32)
+        lap("bank")
+
+    fused, distilled, chunks = [], [], []
+    test_acc, val_acc, pre_acc, history = [], [], [], []
+    for p, m in enumerate(models):
+        if not stacks[p]:
+            fused.append(leaf_norms(g0[p], g0[p]))
+            distilled.append(False)
+            test_acc.append(m.accuracy(g0[p], inputs.test.x, inputs.test.y))
+            val_acc.append(m.accuracy(g0[p], inputs.val.x, inputs.val.y))
+            pre_acc.append(None)
+            continue
+        avg = weighted_mean(stacks[p], weights[p])
+        if feddf:
+            pre_acc.append(None if heterogeneous else
+                           m.accuracy(avg, inputs.test.x, inputs.test.y))
+            out, hist, first = _distill(m, avg, inputs, bank, job,
+                                        seed + t + (p if heterogeneous else 0))
+            history.append(hist)
+            chunks.append(first)
+        else:
+            pre_acc.append(None)
+            out = avg
+        fused.append(leaf_norms(out, g0[p]))
+        distilled.append(feddf)
+        test_acc.append(m.accuracy(out, inputs.test.x, inputs.test.y))
+        val_acc.append(m.accuracy(out, inputs.val.x, inputs.val.y))
+    lap("fusion_and_evaluation")
+    return RoundOne(clients=clients, first_grad=first_grad or {}, bank=bank,
+                    fused=fused, distilled=distilled, chunks=chunks,
+                    test_acc=test_acc, val_acc=val_acc, pre_acc=pre_acc,
+                    ens_acc=ens_acc, seconds=seconds, val_history=history)
+
+
+def _distill(m: Model, student: dict, inputs, bank: np.ndarray, job: dict,
+             seed: int):
+    """Adam on KL(softmax(bank row) || softmax(student)) over batches drawn
+    from the pool, checking validation accuracy every ``eval_every`` steps
+    and keeping the best checkpoint (strictly better replaces; the
+    starting student is never kept).  Returns (best, the validation
+    accuracies, the first check's per-leaf change from ``student`` and
+    gradient norm as Adam's bias-corrected second moment holds it)."""
+    steps, every = int(job["distill_steps"]), int(job["eval_every"])
+    pool = jnp.asarray(inputs.pool)
+    rows = jnp.asarray(bank)
+    params = student
+    mom = jax.tree.map(jnp.zeros_like, params)
+    vel = jax.tree.map(jnp.zeros_like, params)
+    key = jax.random.PRNGKey(seed)
+    best, best_acc, hist, first = student, -1.0, [], None
+    for step in range(steps):
+        params, mom, vel, key = m.distill_step(
+            params, mom, vel, key, jnp.float32(step), pool, rows)
+        if step + 1 == every:
+            corr = 1.0 - ADAM_B2 ** every
+            first = {"change": leaf_norms(params, student),
+                     "grad": {jax.tree_util.keystr(path): float(jnp.sqrt(
+                         jnp.sum(v.astype(jnp.float32)) / corr))
+                         for path, v in
+                         jax.tree_util.tree_leaves_with_path(vel)}}
+        if (step + 1) % every == 0:
+            acc = m.accuracy(params, inputs.val.x, inputs.val.y)
+            hist.append([step + 1, acc])
+            if acc > best_acc:
+                best, best_acc = params, acc
+    return best, hist, first
