@@ -53,7 +53,7 @@ from repro.obs import trace as _trace
 from repro.obs.metrics import REGISTRY
 from repro.core.logit_bank import (TEACHER_FORWARDS, LogitBank,
                                    _ForwardCounter, dequantize_rows,
-                                   resolve_bank)
+                                   resolve_bank, stacked_teacher_count)
 from repro.core.nets import Net
 from repro.data.distill_sources import DistillSource
 from repro.optim.optimizers import adam, apply_updates
@@ -212,13 +212,16 @@ def _resolve_fused(flag):
 
 
 def _count_teachers(teacher_logit_fns, source, batch_size) -> int:
-    """Total K across groups, for the forward-call accounting.  Derived by
-    shape evaluation (same ground truth as the bank builder) so plain
-    callables count correctly too; falls back to the ``n_teachers``
-    attribute stamped by :func:`make_teacher_logits_fn` when the source
-    or a fn cannot be abstractly traced."""
+    """Total K across groups, for the forward-call accounting.  Stamped
+    fns (:func:`make_teacher_logits_fn`) count their stacks' leading
+    axes, as the bank builder does; plain callables are counted by shape
+    evaluation, falling back to an ``n_teachers`` attribute when the
+    source or a fn cannot be abstractly traced."""
     if not teacher_logit_fns:
         return 0
+    k_total = stacked_teacher_count(teacher_logit_fns)
+    if k_total is not None:
+        return k_total
     try:
         x = jax.eval_shape(lambda k: source.sample(k, batch_size),
                            jax.ShapeDtypeStruct((2,), jnp.uint32))

@@ -27,6 +27,7 @@ and the break-even analysis against the on-the-fly path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 import weakref
@@ -36,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.counters import TraceCounter
+from repro.common.pytree import tree_leading_dim
 from repro.obs import trace as _trace
 from repro.obs.metrics import REGISTRY
 from repro.common.options import (BANK_DTYPES, LOGIT_BANK_MODES,
@@ -71,6 +73,18 @@ _ForwardCounter = TraceCounter
 # (and hetero G x) redundancy.  Lives in the unified metrics registry
 # under a dotted name; this alias keeps the historic interface.
 TEACHER_FORWARDS = REGISTRY.counter("core.logit_bank.teacher_forwards")
+
+# Counts TRACES of the bank's forward program: a trace-time side effect,
+# as ``feddf.CHUNK_COMPILES``, so it moves only when jax re-traces — once
+# per run when the teacher stacks cross as arguments, once per build when
+# a plain callable keeps the closure path.
+BANK_COMPILES = REGISTRY.counter("core.logit_bank.bank_compiles")
+
+# Cross-round bank forwards, weakly keyed by the first teacher Net (the
+# idiom of ``feddf._CHUNK_CACHE``).  Values close over the teachers'
+# apply fns, never a Net: a value that referenced its weak key would pin
+# the entry forever (core/client.py's eval caches).
+_FWD_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 
 @dataclasses.dataclass
@@ -177,6 +191,61 @@ def _dtype_name_of(dtype) -> str:
                      f"use one of {sorted(_BANK_DTYPES)}")
 
 
+def stacked_teacher_count(teacher_logit_fns: Sequence[Callable]
+                          ) -> Optional[int]:
+    """Total K over teacher fns that all carry a stamped ``.net`` and
+    ``.stack`` (``feddf.make_teacher_logits_fn``), read off the stacks;
+    None when any is a plain callable, whose K only a trace can tell."""
+    if not teacher_logit_fns or not all(
+            hasattr(f, "net") and hasattr(f, "stack")
+            for f in teacher_logit_fns):
+        return None
+    return sum(tree_leading_dim(f.stack) for f in teacher_logit_fns)
+
+
+def _reduce_rows(t, w_norm, dtype_name: str):
+    """[K, c, C] teacher logits -> (stored rows [c, C], scales or None):
+    the fp32 uniform mean (or ``w_norm``-weighted consensus), quantized
+    per row for the quantized dtypes."""
+    t = t.astype(jnp.float32)
+    mean = (jnp.mean(t, axis=0) if w_norm is None
+            else jnp.tensordot(w_norm, t, axes=([0], [0])))
+    if dtype_name in QUANTIZED_BANK_DTYPES:
+        return quantize_rows(mean, dtype_name)
+    return mean.astype(bank_dtype(dtype_name)), None
+
+
+def _stacked_fwd(nets: Sequence, dtype_name: str, weighted: bool):
+    """The cross-round cached ``fwd(stacks, w_norm, xc)`` for these
+    teacher nets: the stacks (and weights) are ARGUMENTS, so round t+1's
+    fresh uploads reuse round t's program instead of folding their
+    weights into a new one; jax's own signature cache takes new shapes
+    (another K, chunk or pool width)."""
+    per = _FWD_CACHE.get(nets[0])
+    if per is None:
+        per = {}
+        _FWD_CACHE[nets[0]] = per
+    applies = tuple(n.apply for n in nets)
+    # the apply fns themselves, not their nets' ids: the entry holds
+    # them, so no id recycled after a net dies can alias this program
+    key = (applies, dtype_name, weighted)
+    fwd = per.get(key)
+    if fwd is None:
+        def fwd(stacks, w_norm, xc):
+            BANK_COMPILES.add(1)  # trace-time side effect: counts compiles
+
+            def logits(apply, stack):
+                return jax.vmap(lambda p: apply(p, xc, train=False))(stack)
+
+            t = jnp.concatenate(
+                [logits(a, s) for a, s in zip(applies, stacks)], axis=0)
+            return _reduce_rows(t, w_norm if weighted else None, dtype_name)
+
+        fwd = jax.jit(fwd)
+        per[key] = fwd
+    return fwd
+
+
 def build_logit_bank(teacher_logit_fns: Sequence[Callable], pool, *,
                      chunk_size: int = DEFAULT_CHUNK, dtype=jnp.float32,
                      sharding=None, teacher_weights=None) -> LogitBank:
@@ -197,11 +266,14 @@ def build_logit_bank(teacher_logit_fns: Sequence[Callable], pool, *,
     stored rows at build time (the buffered-async staleness-importance
     path, docs/population.md): downstream gathers stay byte-identical in
     shape and cost.  None keeps the historic uniform mean bitwise.
+
+    Stamped teacher fns (``.net`` / ``.stack``) run one cross-round
+    cached program that takes the stacks and weights as arguments
+    (:func:`_stacked_fwd`); plain callables are closed over and re-jitted
+    per build.
     """
     t0 = time.time()
     dtype_name = _dtype_name_of(dtype)
-    storage = bank_dtype(dtype_name)
-    quantized = dtype_name in QUANTIZED_BANK_DTYPES
     pool = jnp.asarray(pool)
     n = int(pool.shape[0])
     c = max(1, min(int(chunk_size), n))
@@ -211,10 +283,14 @@ def build_logit_bank(teacher_logit_fns: Sequence[Callable], pool, *,
         [pool, jnp.zeros((pad,) + pool.shape[1:], pool.dtype)])
         if pad else pool)
 
-    k_total = int(jax.eval_shape(
-        lambda xc: jnp.concatenate(
-            [jnp.asarray(f(xc)) for f in teacher_logit_fns], axis=0),
-        jax.ShapeDtypeStruct((c,) + pool.shape[1:], pool.dtype)).shape[0])
+    k_total = stacked_teacher_count(teacher_logit_fns)
+    stacked = k_total is not None
+    if not stacked:
+        k_total = int(jax.eval_shape(
+            lambda xc: jnp.concatenate(
+                [jnp.asarray(f(xc)) for f in teacher_logit_fns], axis=0),
+            jax.ShapeDtypeStruct((c,) + pool.shape[1:], pool.dtype)
+        ).shape[0])
 
     w_norm = None
     if teacher_weights is not None:
@@ -225,16 +301,18 @@ def build_logit_bank(teacher_logit_fns: Sequence[Callable], pool, *,
                 f"the concatenated teacher axis, got {tuple(w.shape)}")
         w_norm = w / jnp.sum(w)
 
-    @jax.jit
-    def fwd(xc):
-        t = jnp.concatenate(
-            [jnp.asarray(f(xc)) for f in teacher_logit_fns], axis=0)
-        t = t.astype(jnp.float32)
-        mean = (jnp.mean(t, axis=0) if w_norm is None
-                else jnp.tensordot(w_norm, t, axes=([0], [0])))
-        if quantized:
-            return quantize_rows(mean, dtype_name)
-        return mean.astype(storage), None
+    if stacked:
+        fwd = functools.partial(
+            _stacked_fwd([f.net for f in teacher_logit_fns], dtype_name,
+                         w_norm is not None),
+            tuple(f.stack for f in teacher_logit_fns), w_norm)
+    else:
+        @jax.jit
+        def fwd(xc):
+            BANK_COMPILES.add(1)  # trace-time side effect: counts compiles
+            t = jnp.concatenate(
+                [jnp.asarray(f(xc)) for f in teacher_logit_fns], axis=0)
+            return _reduce_rows(t, w_norm, dtype_name)
 
     chunks, scale_chunks = [], []
     for i in range(n_chunks):

@@ -330,6 +330,18 @@ def test_feddf_chunk_compiles_once_across_rounds(problem):
     assert CHUNK_COMPILES.count == 1, CHUNK_COMPILES.count
 
 
+def test_feddf_bank_forward_compiles_once_across_rounds(problem):
+    from repro.core.logit_bank import BANK_COMPILES
+    train, val, test, parts, src = problem
+    net = mlp(2, 3, hidden=(16, 16))
+    BANK_COMPILES.reset()
+    res = run_federated(net, train, parts, val, test, small_cfg(rounds=3),
+                        source=src)
+    # every round builds a bank from fresh uploads, one trace in all
+    assert [l.bank for l in res.logs] == ["bank"] * 3
+    assert BANK_COMPILES.count == 1, BANK_COMPILES.count
+
+
 def test_feddf_chunk_cache_shared_across_drivers(problem):
     """The async driver's fusion thread must reuse the same compiled
     chunk the sync path built (same net/source/fusion config)."""

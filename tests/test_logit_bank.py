@@ -204,6 +204,96 @@ def test_forward_counter_tracks_build():
     assert TEACHER_FORWARDS.count == 2 * 4
 
 
+def _direct_rows(tfns, pool, w=None):
+    """The bank's rows computed straight from the teacher fns (fp32)."""
+    t = jnp.concatenate([tfn(jnp.asarray(pool)) for tfn in tfns],
+                        axis=0).astype(jnp.float32)
+    if w is None:
+        return jnp.mean(t, axis=0)
+    return jnp.tensordot(jnp.asarray(w, jnp.float32) / np.sum(w), t,
+                         axes=([0], [0]))
+
+
+@pytest.mark.parametrize("case", ["homogeneous", "heterogeneous", "weighted",
+                                  "int8"])
+def test_bank_forward_compiles_once_across_rounds(case):
+    """Fresh uploads of one shape every round: the stamped path passes the
+    stacks as arguments, so three builds trace the bank forward once (the
+    per-round re-compile this replaces traced it three times)."""
+    from repro.core.logit_bank import BANK_COMPILES, dequantize_rows
+    nets = [mlp(2, 4, hidden=(16,), name="a")]
+    if case == "heterogeneous":
+        nets.append(mlp(2, 4, hidden=(24,), name="b"))
+    dtype = "int8" if case == "int8" else "float32"
+    pool = RNG.uniform(-2, 2, (130, 2)).astype(np.float32)  # padded chunk
+    BANK_COMPILES.reset()
+    for rnd in range(3):
+        tfns = [make_teacher_logits_fn(n, _stack(n, 3, seed0=10 * rnd + i))
+                for i, n in enumerate(nets)]
+        k = 3 * len(nets)
+        w = (RNG.uniform(0.5, 2.0, k) if case == "weighted" else None)
+        bank = build_logit_bank(tfns, pool, chunk_size=64, dtype=dtype,
+                                teacher_weights=w)
+        assert bank.n_teachers == k and bank.logits.shape == (130, 4)
+        rows = dequantize_rows(bank.logits, bank.scales)
+        tol = 0.05 if case == "int8" else 1e-5
+        np.testing.assert_allclose(np.asarray(rows),
+                                   np.asarray(_direct_rows(tfns, pool, w)),
+                                   atol=tol, rtol=tol)
+    assert BANK_COMPILES.count == 1, BANK_COMPILES.count
+
+
+def test_plain_callable_bank_matches_stamped_rows():
+    """A plain callable has no stack to pass, so it keeps the per-build
+    closure (one trace per build) and yields the stamped path's rows."""
+    from repro.core.logit_bank import BANK_COMPILES
+    net = mlp(2, 4, hidden=(16,))
+    stack = _stack(net, 4)
+    tfn = make_teacher_logits_fn(net, stack)
+    raw = lambda x: jax.vmap(  # noqa: E731 — deliberately attribute-less
+        lambda p: net.apply(p, x, train=False))(stack)
+    pool = RNG.uniform(-2, 2, (100, 2)).astype(np.float32)
+    stamped = build_logit_bank([tfn], pool, chunk_size=50)
+    BANK_COMPILES.reset()
+    plain = [build_logit_bank([raw], pool, chunk_size=50) for _ in range(2)]
+    assert BANK_COMPILES.count == 2
+    for bank in plain:
+        assert bank.n_teachers == 4
+        np.testing.assert_allclose(np.asarray(bank.logits),
+                                   np.asarray(stamped.logits),
+                                   atol=1e-6, rtol=1e-6)
+
+
+def _weight_constants(hlo: str, stack) -> list:
+    """Lines of ``hlo`` that define a constant shaped like a weight leaf."""
+    shapes = {"x".join(map(str, leaf.shape)) + "xf32"
+              for leaf in jax.tree.leaves(stack)}
+    return [line for line in hlo.splitlines()
+            if "constant" in line
+            and any(line.rstrip().endswith(f"tensor<{s}>") for s in shapes)]
+
+
+def test_bank_program_carries_no_teacher_weights():
+    """The stamped bank forward takes the weights as arguments: its
+    lowered program holds no constant of a weight leaf's shape and does
+    not grow with the width, where the closure path embeds them all."""
+    from repro.core.logit_bank import _stacked_fwd
+    xc = jnp.zeros((16, 2), jnp.float32)
+    sizes = []
+    for hidden in (64, 256):
+        net = mlp(2, 3, hidden=(hidden,))
+        stack = _stack(net, 4)
+        hlo = _stacked_fwd([net], "float32", False).lower(
+            (stack,), None, xc).as_text()
+        assert _weight_constants(hlo, stack) == []
+        sizes.append(len(hlo))
+        tfn = make_teacher_logits_fn(net, stack)
+        closure = jax.jit(lambda x: tfn(x)).lower(xc).as_text()
+        assert _weight_constants(closure, stack)  # the check can see them
+    # only the shapes' digits differ between the two widths
+    assert abs(sizes[1] - sizes[0]) < 100, sizes
+
+
 # ---------------------------------------------------------------------------
 # source pool / index interface
 # ---------------------------------------------------------------------------
