@@ -66,7 +66,6 @@ def main(argv=None) -> int:
     ap.add_argument("--rounds", type=int, default=4)
     args = ap.parse_args(argv)
     cell, config, traffic, _ = run.load_cell(args.workload)
-    import jax
     try:
         devices = run.check_devices(int(cell["chips"]))
     except run.NoChip as e:
@@ -76,13 +75,14 @@ def main(argv=None) -> int:
     from repro.drivers.sync import SyncDriver
     from repro.obs import trace as obs
 
-    models = run.model_dicts(config)
+    kind = run.config_kind(config)
+    models = run.model_dicts(config, kind)
     fl_seed = int(args.seed) % run.SEED_SPAN
     inp = run.inputs_mod.make_inputs(args.seed, models[0], traffic,
                                      len(models))
-    engine, _ = run.build_engine(config, traffic, inp, fl_seed)
-    globals0 = engine.init_globals()
-    jax.block_until_ready(globals0)
+    engine, _ = run.build_engine(config, traffic, inp, fl_seed, kind,
+                                 int(cell["chips"]))
+    globals0 = run.initial_globals(engine)
 
     plan = [(i % 4) in (0, 3) for i in range(args.rounds)]
     rows, ends = [], []

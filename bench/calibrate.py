@@ -18,6 +18,8 @@ in the program's round 1 on the control seeds:
                     path off the chip) takes the first half of each batch
     kl_temperature  the distillation loss runs at twice the temperature
     kl_scale        the distillation loss comes out doubled
+    exchange        on several chips, the exchange between them left out:
+                    the mean and the bank take the first chip's clients
 
 A step that leaves its state unchanged (``unchanged`` for the clients,
 ``distill_unchanged`` for a distillation chunk) reads 1 on the change it
@@ -119,6 +121,22 @@ def plant(engine, fault: str):
             (lambda params, xb, *rest: __import__("jax").tree.map(
                 lambda a: jnp.broadcast_to(a, (xb.shape[0],) + a.shape),
                 params)) for _ in engine.nets]
+    elif fault == "exchange":
+        if engine.mesh is None:
+            raise ValueError("the exchange fault needs a cell on several "
+                             "chips")
+        import jax
+        aggregate = engine.aggregate
+
+        def aggregate_(t, groups, state):
+            for g in groups:
+                if g.stack is not None:
+                    leaf = jax.tree.leaves(g.stack)[0]
+                    per = leaf.sharding.shard_shape(leaf.shape)[0]
+                    g.stack = jax.tree.map(lambda a: a[:per], g.stack)
+                    g.weights = g.weights[:per]
+            return aggregate(t, groups, state)
+        engine.aggregate = aggregate_
     elif fault in ("kl_half_batch", "kl_temperature", "kl_scale"):
         _plant_kl(fault)
     elif fault == "distill_unchanged":
@@ -133,16 +151,18 @@ def plant(engine, fault: str):
         raise ValueError(f"unknown fault {fault!r}")
 
 
-def program_round_one(config, traffic, seed: int, fault=None):
+def program_round_one(config, traffic, seed: int, kind, fault=None,
+                      chips: int = 1):
     """(inputs, proto, the program's round-1 outputs) for ``seed``."""
     import jax
     from repro.core import logit_bank
     from repro.drivers.sync import SyncDriver
 
-    models = run.model_dicts(config)
+    models = run.model_dicts(config, kind)
     fl_seed = int(seed) % run.SEED_SPAN
     inp = run.inputs_mod.make_inputs(seed, models[0], traffic, len(models))
-    engine, proto = run.build_engine(config, traffic, inp, fl_seed)
+    engine, proto = run.build_engine(config, traffic, inp, fl_seed, kind,
+                                     chips)
     saved = patched()
     if fault is not None:
         plant(engine, fault)
@@ -154,7 +174,7 @@ def program_round_one(config, traffic, seed: int, fault=None):
         tap.close()
 
     win = run.Window(None, len(models), round_one, None, None)
-    g0 = engine.init_globals()
+    g0 = run.initial_globals(engine)
     try:
         results, _, _ = SyncDriver().run(engine, log_fn=win,
                                          init_globals=g0)
@@ -170,10 +190,10 @@ def program_round_one(config, traffic, seed: int, fault=None):
     return inp, proto, fl_seed, prog
 
 
-def reference(models, traffic, inp, proto, fl_seed, dtype):
+def reference(models, traffic, inp, proto, fl_seed, dtype, kind):
     import jax.numpy as jnp
     import reference as ref_mod
-    ms = [ref_mod.Model(m, traffic, dtype=getattr(jnp, dtype))
+    ms = [ref_mod.Model(m, traffic, kind, dtype=getattr(jnp, dtype))
           for m in models]
     return ref_mod.round_one(ms, traffic, inp, proto, fl_seed,
                              len(models) > 1)
@@ -212,16 +232,19 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     import compare
     cell, config, traffic, _ = run.load_cell(args.workload)
+    kind = run.config_kind(config)
+    chips = int(cell["chips"])
     if args.chips:
-        run.check_devices(int(cell["chips"]))
+        run.check_devices(chips)
         run.use_compile_cache(run.CACHE_DIR)
-    models = run.model_dicts(config)
+    models = run.model_dicts(config, kind)
     emit = lambda d: print(json.dumps(d), flush=True)
     for seed in sorted(set(args.seeds) | set(args.control_seeds)):
         t0 = time.perf_counter()
         inp, proto, fl_seed, prog = program_round_one(
-            config, traffic, seed)
-        ref = reference(models, traffic, inp, proto, fl_seed, "float32")
+            config, traffic, seed, kind, chips=chips)
+        ref = reference(models, traffic, inp, proto, fl_seed, "float32",
+                        kind)
         if args.dump:
             dump(os.path.join(args.dump, f"{seed}-reference.json"), ref, ref)
             dump(os.path.join(args.dump, f"{seed}-program.json"), prog, ref)
@@ -235,7 +258,8 @@ def main(argv=None) -> int:
         if seed not in args.control_seeds:
             continue
         t0 = time.perf_counter()
-        ctl = reference(models, traffic, inp, proto, fl_seed, "bfloat16")
+        ctl = reference(models, traffic, inp, proto, fl_seed, "bfloat16",
+                        kind)
         if args.dump:
             dump(os.path.join(args.dump, f"{seed}-control.json"), ctl, ref)
         emit({"seed": seed, "kind": "control:bfloat16",
@@ -245,7 +269,8 @@ def main(argv=None) -> int:
               "worst": compare.worst_leaves(ctl, ref)})
         for fault in args.faults:
             t0 = time.perf_counter()
-            _, _, _, bad = program_round_one(config, traffic, seed, fault)
+            _, _, _, bad = program_round_one(config, traffic, seed, kind,
+                                             fault, chips)
             if args.dump:
                 dump(os.path.join(args.dump, f"{seed}-{fault}.json"), bad,
                      ref)

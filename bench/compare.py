@@ -31,7 +31,13 @@ round-off tilts most changes from seed to seed (PERF.md).
 Leaves whose first gradient in the reference is under a thousandth of the
 median leaf's move by round-off alone and are left out of the changes
 (the clients' first local gradient for client_change, the distillation's
-for the distill numbers).
+for the distill numbers).  A distillation's leaves are also left out
+where their gradient in the reference is under a thousandth of the
+clients' median leaf: there the student already agrees with the bank to
+the last digits of a saturated softmax, Adam divides a gradient far below
+its epsilon, and the student moves by round-off alone.  A distillation
+with no leaf left is left out of the distill numbers; where every one is,
+they are not reported (``not_compared`` counts them).
 
 Read for the record and not compared (``not_compared``): the first
 chunk's worst leaves, and the student after the whole distillation (its
@@ -41,7 +47,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -61,6 +67,15 @@ def load_limits(path: str = LIMITS_FILE) -> Dict[str, float]:
 def kept_leaves(first_grad: Dict[str, float]) -> List[str]:
     med = float(np.median(list(first_grad.values())))
     return [k for k, g in first_grad.items() if g >= GRAD_FLOOR * med]
+
+
+def distill_leaves(chunk_grad: Dict[str, float],
+                   first_grad: Dict[str, float]) -> List[str]:
+    """The leaves of one distillation that count: those ``kept_leaves``
+    keeps of its own gradient, at or above a thousandth of the median leaf
+    of the clients' first gradient."""
+    floor = GRAD_FLOOR * float(np.median(list(first_grad.values())))
+    return [k for k in kept_leaves(chunk_grad) if chunk_grad[k] >= floor]
 
 
 def leaf_gaps(prog: Dict[str, float], ref: Dict[str, float],
@@ -102,23 +117,28 @@ def worst_leaves(prog, ref, top: int = 3) -> Dict[str, list]:
     for part in ("change", "grad"):
         rows = []
         for g, (pc, rc) in enumerate(zip(prog.chunks, ref.chunks)):
-            rows += [(v, f"d{g}{k}", pc[part][k], rc[part][k]) for k, v in
-                     leaf_gaps(pc[part], rc[part],
-                               kept_leaves(rc["grad"])).items()]
+            keep = distill_leaves(rc["grad"], ref.first_grad)
+            if keep:
+                rows += [(v, f"d{g}{k}", pc[part][k], rc[part][k])
+                         for k, v in leaf_gaps(pc[part], rc[part],
+                                               keep).items()]
         out[f"distill_{part}"] = sorted(rows, reverse=True)[:top]
     return out
 
 
-def chunk_gaps(prog, ref, part: str, stat=max) -> float:
+def chunk_gaps(prog, ref, part: str, stat=max) -> Optional[float]:
     """``stat`` (the worst leaf, or the median) of the first chunks' leaf
     gaps of ``part`` (``change`` or ``grad``), the largest over the
-    distillations; inf when the program ran another number of them."""
+    distillations that count (:func:`distill_leaves`); None when none
+    does, inf when the program ran another number of them."""
     if len(prog.chunks) != len(ref.chunks):
         return float("inf")
-    out = 0.0
+    out = None
     for pc, rc in zip(prog.chunks, ref.chunks):
-        gaps = leaf_gaps(pc[part], rc[part], kept_leaves(rc["grad"]))
-        out = max(out, float(stat(list(gaps.values()))))
+        keep = distill_leaves(rc["grad"], ref.first_grad)
+        if keep:
+            gaps = leaf_gaps(pc[part], rc[part], keep)
+            out = max(out or 0.0, float(stat(list(gaps.values()))))
     return out
 
 
@@ -155,22 +175,27 @@ def numbers(prog, ref) -> Dict[str, float]:
             accs += [_gap(prog.test_acc[p], ref.test_acc[p]),
                      _gap(prog.val_acc[p], ref.val_acc[p])]
     out["accuracy"] = max(accs)
-    if ref.chunks:
-        out["distill_change"] = chunk_gaps(prog, ref, "change", np.median)
-        out["distill_grad"] = chunk_gaps(prog, ref, "grad", np.median)
+    for part in ("change", "grad") if ref.chunks else ():
+        gap = chunk_gaps(prog, ref, part, np.median)
+        if gap is not None:
+            out[f"distill_{part}"] = gap
     return {k: float(v) for k, v in out.items()}
 
 
 def not_compared(prog, ref) -> Dict[str, float]:
     """What distillation produced, read for the record: the median leaf's
     gap of the fused globals' change and the largest gap of their test
-    and validation accuracies, over the distilled groups."""
+    and validation accuracies, over the distilled groups, and how many
+    distillations the distill numbers leave out."""
     keep = kept_leaves(ref.first_grad)
     out = {"fused_change": 0.0, "distilled_accuracy": 0.0,
-           "distill_change_worst": 0.0, "distill_grad_worst": 0.0}
+           "distill_change_worst": 0.0, "distill_grad_worst": 0.0,
+           "distill_left_out": float(sum(
+               not distill_leaves(rc["grad"], ref.first_grad)
+               for rc in ref.chunks))}
     if ref.chunks:
         for part in ("change", "grad"):
-            out[f"distill_{part}_worst"] = chunk_gaps(prog, ref, part)
+            out[f"distill_{part}_worst"] = chunk_gaps(prog, ref, part) or 0.0
     for p, done in enumerate(ref.distilled):
         if done:
             gaps = leaf_gaps(prog.fused[p], ref.fused[p], keep)

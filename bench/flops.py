@@ -1,32 +1,16 @@
 """Operations and bytes the algorithm needs, from shapes alone.
 
-Model FLOPs per token of the transformer classifier (``reference.forward``)
-follow the usual count: a matmul of an [m, k] by a [k, n] operand is
-2·m·k·n, a token's forward pass multiplies by every non-embedding weight
-once (2·N) and attends over the sequence (2·s·d for QK^T and 2·s·d for
-AV per layer), and training adds the backward pass at twice the forward's
-cost.  The embedding and position tables are gathered, not multiplied.
+A round's model FLOPs come from the model kind (``bench/models/<kind>.py``:
+``forward_flops_per_token``, over the active parameters); training adds
+the backward pass at twice the forward's cost.
 """
 from __future__ import annotations
 
 import numpy as np
 
 
-def non_embedding_params(model: dict) -> int:
-    d, n_layers = int(model["d_model"]), int(model["n_layers"])
-    n_cls = int(model["n_classes"])
-    per_layer = 3 * d * d + d * d + 4 * d * d + 4 * d * d + 2 * d
-    return n_layers * per_layer + d * n_cls + n_cls
-
-
-def forward_flops_per_token(model: dict) -> float:
-    d, n_layers, s = (int(model["d_model"]), int(model["n_layers"]),
-                      int(model["seq_len"]))
-    return 2.0 * non_embedding_params(model) + 4.0 * n_layers * s * d
-
-
-def train_flops_per_token(model: dict) -> float:
-    return 3.0 * forward_flops_per_token(model)
+def train_flops_per_token(kind, model: dict) -> float:
+    return 3.0 * kind.forward_flops_per_token(model)
 
 
 def kl_bank_cost(b: int, n: int, c: int, bank_itemsize: int):
@@ -48,13 +32,15 @@ def kl_bank_cost(b: int, n: int, c: int, bank_itemsize: int):
     return 16.0 * b * c, float(fwd_bytes + bwd_bytes)
 
 
-def round_flops(model_of, job: dict, inputs, proto, active) -> dict:
+def round_flops(kind, model_of, job: dict, inputs, proto, active) -> dict:
     """Model FLOPs of one round by phase, real work only: the local steps
     of the active clients (not the masked padding), the bank over the
     pool, the distillation steps and the evaluations (distillation's
     validation checks, the pre-distillation and final accuracies, and the
     ensemble accuracy of a heterogeneous round).  ``model_of(p)`` is the
-    model dict of prototype ``p``."""
+    model dict of prototype ``p``, of the model kind ``kind``."""
+    fwd_tok = kind.forward_flops_per_token
+    train_tok = lambda m: train_flops_per_token(kind, m)
     batch, epochs = int(job["local_batch_size"]), int(job["local_epochs"])
     out = {"client": 0.0, "bank": 0.0, "distill": 0.0, "eval": 0.0}
     groups = sorted({proto[int(k)] for k in active})
@@ -66,21 +52,21 @@ def round_flops(model_of, job: dict, inputs, proto, active) -> dict:
         m = model_of(proto[int(k)])
         s = int(m["seq_len"])
         steps = epochs * max(1, len(inputs.parts[int(k)]) // batch)
-        out["client"] += steps * batch * s * train_flops_per_token(m)
+        out["client"] += steps * batch * s * train_tok(m)
         if feddf:
-            out["bank"] += len(inputs.pool) * s * forward_flops_per_token(m)
+            out["bank"] += len(inputs.pool) * s * fwd_tok(m)
         if hetero:
-            out["eval"] += n_test * s * forward_flops_per_token(m)
+            out["eval"] += n_test * s * fwd_tok(m)
     for p in range(n_groups):
         m = model_of(p)
         s = int(m["seq_len"])
-        fwd = forward_flops_per_token(m) * s
+        fwd = fwd_tok(m) * s
         out["eval"] += (n_test + n_val) * fwd
         if p not in groups or not feddf:
             continue
         steps = int(job["distill_steps"])
         out["distill"] += steps * int(job["distill_batch"]) * s \
-            * train_flops_per_token(m)
+            * train_tok(m)
         out["eval"] += (steps // int(job["eval_every"])) * n_val * fwd
         if not hetero:
             out["eval"] += n_test * fwd

@@ -4,10 +4,12 @@ It imports nothing of the program and takes nothing the program made: it
 draws its own weights from the seed, trains its own clients, builds its
 own logit bank and distils its own student, from the same inputs and by
 the same published equations (the paper's Algorithms 1-3, the model's
-layer equations, Adam, the cosine schedule).  It runs in float32 at
-``highest`` matmul precision; ``dtype="bfloat16"`` gives the lower-
-precision control, which runs the same code with parameters, optimizer
-state and activations in bfloat16.
+layer equations, Adam, the cosine schedule).  The model comes from the
+configuration's model kind (``bench/models/<kind>.py``: its weights,
+forward pass and auxiliary training loss); the round is this file's.  It
+runs in float32 at ``highest`` matmul precision; ``dtype="bfloat16"``
+gives the lower-precision control, which runs the same code with
+parameters, optimizer state and activations in bfloat16.
 
 It runs one client, one 512-row block or one step at a time, so that it
 fits beside nothing else on the chip once the program's state is freed.
@@ -15,7 +17,6 @@ fits beside nothing else on the chip once the program's state is freed.
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 from typing import Dict, List, Optional
 
@@ -25,57 +26,6 @@ import numpy as np
 
 EVAL_BLOCK = 512
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
-
-
-# -- the model: a pre-norm transformer encoder classifier ---------------------
-
-def init_params(key, model: dict, dtype) -> dict:
-    """Weights from ``key``: normal draws scaled by 1/sqrt(fan-in), in the
-    order one split of ``key`` hands them out."""
-    d, n_layers = int(model["d_model"]), int(model["n_layers"])
-    vocab, seq, n_cls = (int(model["vocab_size"]), int(model["seq_len"]),
-                         int(model["n_classes"]))
-    ks = jax.random.split(key, 3 + 4 * n_layers)
-    nrm = jax.random.normal
-    p = {"embed": nrm(ks[0], (vocab, d)) * 0.05,
-         "pos": nrm(ks[1], (seq, d)) * 0.05,
-         "head": {"w": nrm(ks[2], (d, n_cls)) * (1.0 / math.sqrt(d)),
-                  "b": jnp.zeros((n_cls,))}}
-    for l in range(n_layers):
-        k = ks[3 + 4 * l:7 + 4 * l]
-        s = 1.0 / math.sqrt(d)
-        p[f"layer_{l}"] = {
-            "wqkv": nrm(k[0], (d, 3 * d)) * s,
-            "wo": nrm(k[1], (d, d)) * s,
-            "w1": nrm(k[2], (d, 4 * d)) * s,
-            "w2": nrm(k[3], (4 * d, d)) * (1.0 / math.sqrt(4 * d)),
-            "ln1": jnp.ones((d,)), "ln2": jnp.ones((d,))}
-    return jax.tree.map(lambda a: a.astype(dtype), p)
-
-
-def _rms(w, x):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
-
-
-def forward(params: dict, x, model: dict):
-    """Logits [B, C]: token + position embedding, then per layer
-    h += Wo attn(RMS(h)); h += W2 gelu(W1 RMS(h)); mean-pool; linear head."""
-    n_heads, n_layers = int(model["n_heads"]), int(model["n_layers"])
-    b, s = x.shape
-    h = params["embed"][x] + params["pos"][None, :s]
-    d = h.shape[-1]
-    hd = d // n_heads
-    for l in range(n_layers):
-        p = params[f"layer_{l}"]
-        y = _rms(p["ln1"], h)
-        q, k, v = jnp.split(y @ p["wqkv"], 3, axis=-1)
-        q, k, v = (a.reshape(b, s, n_heads, hd) for a in (q, k, v))
-        att = jax.nn.softmax(
-            jnp.einsum("bshd,bthd->bhst", q, k) / math.sqrt(hd), axis=-1)
-        h = h + jnp.einsum("bhst,bthd->bshd", att, v).reshape(b, s, d) \
-            @ p["wo"]
-        h = h + jax.nn.gelu(_rms(p["ln2"], h) @ p["w1"]) @ p["w2"]
-    return jnp.mean(h, axis=1) @ params["head"]["w"] + params["head"]["b"]
 
 
 # -- Adam (Kingma & Ba), as the paper trains clients and the student ----------
@@ -101,10 +51,11 @@ def cosine_lr(lr: float, total: int, step):
 
 class Model:
     """The jitted pieces of one configuration at one precision: float32 at
-    ``highest`` matmul precision, or the bfloat16 control."""
+    ``highest`` matmul precision, or the bfloat16 control.  ``kind`` is the
+    model kind's module; the clients' loss adds its auxiliary term."""
 
-    def __init__(self, model: dict, job: dict, dtype=jnp.float32):
-        self.model, self.dtype = model, jnp.dtype(dtype)
+    def __init__(self, model: dict, job: dict, kind, dtype=jnp.float32):
+        self.model, self.kind, self.dtype = model, kind, jnp.dtype(dtype)
         prec = "highest" if self.dtype == jnp.float32 else "default"
         local_lr = float(job["local_lr"])
         distill_lr = float(job.get("distill_lr", 1e-3))
@@ -113,16 +64,19 @@ class Model:
         batch = int(job.get("distill_batch", 1))
         cast = lambda a: jnp.asarray(a, jnp.float32).astype(self.dtype)
 
-        def fwd(params, x):
+        def fwd_aux(params, x):
             with jax.default_matmul_precision(prec):
-                return forward(params, x, model)
+                return kind.forward(params, x, model)
 
-        def xent(params, x, y):
-            logp = jax.nn.log_softmax(fwd(params, x), axis=-1)
-            return -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1))
+        fwd = lambda params, x: fwd_aux(params, x)[0]
+
+        def client_loss(params, x, y):
+            logits, aux = fwd_aux(params, x)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.mean(jnp.take_along_axis(logp, y[:, None], -1)) + aux
 
         def client_step(params, m, v, x, y, step):
-            grads = jax.grad(xent)(params, x, y)
+            grads = jax.grad(client_loss)(params, x, y)
             norms = jax.tree.map(lambda g: jnp.sqrt(jnp.sum(jnp.square(
                 g.astype(jnp.float32)))), grads)
             params, m, v = _adam(params, m, v, grads, step, cast(local_lr),
@@ -149,7 +103,8 @@ class Model:
         self.logits = jax.jit(lambda p, x: fwd(p, x).astype(jnp.float32))
 
     def init(self, seed: int) -> dict:
-        return init_params(jax.random.PRNGKey(seed), self.model, self.dtype)
+        return self.kind.init_params(jax.random.PRNGKey(seed), self.model,
+                                     self.dtype)
 
     def predict(self, params, x: np.ndarray) -> np.ndarray:
         """Logits [n, C] in float32, 512 rows at a time (the last block

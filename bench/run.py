@@ -4,11 +4,13 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (``BENCHMARK.json``: ``workloads``) names a configuration
-(``bench/configs/<config>.json``, the client and server models' sizes)
-and a traffic mix (``bench/traffic/<traffic>.json``, the federated job).
-The run makes every input from ``--seed`` (``inputs.py``), builds the
-program's ``RoundEngine`` over them, and drives the program's ``sync``
-driver for whole rounds:
+(``bench/configs/<config>.json``, the client and server models' sizes,
+of the model kind its ``"model"`` key names, ``bench/models/<kind>.py``),
+a traffic mix (``bench/traffic/<traffic>.json``, the federated job) and
+its chips.  The run makes every input from ``--seed`` (``inputs.py``),
+builds the program's ``RoundEngine`` over them, with the client axis
+sharded over the chips when there are several, and drives the program's
+``sync`` driver for whole rounds:
 
 * set-up: imports, inputs, the engine, and round 1, which compiles (or
   fetches from the compile cache) every program the window uses, and
@@ -54,12 +56,16 @@ import inputs as inputs_mod  # noqa: E402
 #: paths inside the checkout (the path is part of the cache's key)
 CACHE_DIR = os.path.join(ROOT, ".bench_cache", "jax")
 TRACE_DIR = os.path.join(ROOT, ".bench_cache", "trace")
-#: room for every program of a cell, while the bank's per-round program,
-#: which folds the teachers' weights in as constants (1.6 to 2.0 GB, and
-#: never hit again), stays out of the cache and off the disk
+#: room for every program of a cell: JAX evicts the least recently used
+#: programs past this size, and the machine's own cap (192 MiB) evicted
+#: programs of the ladder cell
 CACHE_BYTES = 2 ** 30
 #: the program's seeds must stay below 2**31 after per-round offsets
 SEED_SPAN = 2 ** 31 - 2 ** 20
+#: the model kinds, one module each, and the kind of a configuration
+#: without a "model" key
+MODELS = os.path.join(BENCH, "models")
+DEFAULT_KIND = "tiny_transformer"
 
 
 class NoChip(Exception):
@@ -87,20 +93,37 @@ def load_cell(name: str, root: str = ROOT):
     return cell, config, traffic, manifest
 
 
-def model_dicts(config: dict) -> list:
+def load_kind(name: str, folder: str = MODELS):
+    """The model kind ``<folder>/<name>.py``.  A kind gives
+
+    * ``model_dict(prototype, config)``: the model dict of one of the
+      configuration's prototypes, with every size the kind needs and
+      ``vocab_size``, ``seq_len`` and ``n_classes``, which the inputs read;
+    * ``net(bundle, model)``: the program's net, from
+      ``api/registries.py:get_model`` by the kind's registry name;
+    * ``init_params(key, model, dtype)`` and ``forward(params, x, model)``:
+      the reference's weights, whose leaves carry the program's leaf
+      paths, and its (logits, auxiliary training loss), the loss 0.0 in a
+      kind without one;
+    * ``forward_flops_per_token(model)``: model FLOPs of one token's
+      forward pass, over the active parameters."""
+    path = os.path.join(folder, f"{name}.py")
+    if not os.path.isfile(path):
+        found = sorted(f[:-3] for f in os.listdir(folder)
+                       if f.endswith(".py") and not f.startswith("_"))
+        raise ValueError(f"unknown model kind {name!r}; kinds in "
+                         f"{folder}: {found}")
+    return _load_module(path, f"model_kind_{name}")
+
+
+def config_kind(config: dict, folder: str = MODELS):
+    """The model kind the configuration's ``"model"`` key names."""
+    return load_kind(config.get("model", DEFAULT_KIND), folder)
+
+
+def model_dicts(config: dict, kind) -> list:
     """One model dict per prototype, in the reference's terms."""
-    out = []
-    for p in config["prototypes"]:
-        if int(p["hidden_dim"]) != 4 * int(p["dim"]):
-            raise ValueError(f"{p['name']}: the model's feed-forward width "
-                             f"is 4 x dim")
-        out.append({"name": p["name"], "d_model": int(p["dim"]),
-                    "n_layers": int(p["n_layers"]),
-                    "n_heads": int(p["n_heads"]),
-                    "vocab_size": int(config["vocab_size"]),
-                    "seq_len": int(config["max_position_embeddings"]),
-                    "n_classes": int(config["num_labels"])})
-    return out
+    return [kind.model_dict(p, config) for p in config["prototypes"]]
 
 
 # -- the chip, the compile cache and compile events ---------------------------
@@ -167,26 +190,27 @@ class CompileWatch:
 
 # -- the program --------------------------------------------------------------
 
-def build_engine(config: dict, traffic: dict, inp, seed: int):
+def build_engine(config: dict, traffic: dict, inp, seed: int, kind,
+                 chips: int = 1):
     """The program's ``RoundEngine`` over the benchmark's inputs: nets from
     the model registry at the configuration's widths, the job's
-    ``FLConfig``, and the unlabeled pool as the distillation source."""
-    from repro.api.registries import TaskBundle, get_model
+    ``FLConfig``, and the unlabeled pool as the distillation source.  On
+    several chips the client axis shards over a mesh of them
+    (``launch/mesh.py:make_client_mesh``)."""
+    from repro.api.registries import TaskBundle
     from repro.core.engine import FLConfig, RoundEngine
     from repro.core.feddf import FusionConfig
     from repro.data.distill_sources import UnlabeledDataset
     from repro.data.synthetic import Dataset
 
-    models = model_dicts(config)
+    models = model_dicts(config, kind)
     m0 = models[0]
     bundle = TaskBundle(dataset=None, distill_shape=(m0["seq_len"],),
                         vocab=m0["vocab_size"],
                         model_kwargs={"vocab": m0["vocab_size"],
                                       "n_classes": m0["n_classes"],
                                       "seq_len": m0["seq_len"]})
-    nets = [get_model("tiny_transformer")(
-        bundle, d_model=m["d_model"], n_layers=m["n_layers"],
-        n_heads=m["n_heads"], name=m["name"]) for m in models]
+    nets = [kind.net(bundle, m) for m in models]
     feddf = traffic["strategy"] == "feddf"
     fusion = FusionConfig()
     if feddf:
@@ -214,16 +238,35 @@ def build_engine(config: dict, traffic: dict, inp, seed: int):
                          ds(inp.test), cfg,
                          source=UnlabeledDataset(inp.pool) if feddf else None,
                          heterogeneous=len(nets) > 1)
+    if chips > 1:
+        from repro.launch.mesh import make_client_mesh
+        engine.attach_mesh(make_client_mesh(chips))
     return engine, proto
+
+
+def initial_globals(engine) -> list:
+    """The engine's initial globals, replicated over its mesh when it has
+    one: the fused globals of every later round come back so placed, and
+    round 1 must compile the programs they take.  The program's own
+    drivers start from ``engine.init_globals()`` unplaced, so on a mesh
+    they compile the client update again in round 2 (PERF.md)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+    globals0 = engine.init_globals()
+    if engine.mesh is not None:
+        globals0 = jax.device_put(
+            globals0, NamedSharding(engine.mesh, PartitionSpec()))
+    return jax.block_until_ready(globals0)
 
 
 class RoundOneTap:
     """Reads what round 1 produced through the engine's own phase calls:
-    each client's change from the initial global (per leaf), the logit
-    bank rows, each group's fused change, and for each distillation its
-    first chunk (the compiled program's own call): per leaf, the change of
-    the student over the chunk's steps and the gradient norm that Adam's
-    second moment holds after them.  Removed after round 1."""
+    each client's change from the initial global (per leaf), how each
+    group's client stack lies over the devices, the logit bank rows, each
+    group's fused change, and for each distillation its first chunk (the
+    compiled program's own call): per leaf, the change of the student
+    over the chunk's steps and the gradient norm that Adam's second
+    moment holds after them.  Removed after round 1."""
 
     def __init__(self, engine):
         import jax
@@ -233,7 +276,7 @@ class RoundOneTap:
 
         self.engine, self.feddf = engine, feddf_mod
         self.clients = self.fused = self.bank = None
-        self.chunks = []
+        self.chunks, self.layout = [], []
         self._g0 = None
 
         def norms(tree, base, stacked):
@@ -286,6 +329,11 @@ class RoundOneTap:
                 if g.stack is None:
                     self.clients.append([])
                     continue
+                leaf = jax.tree.leaves(g.stack)[0]
+                self.layout.append(
+                    (leaf.shape[0],
+                     sorted(d.id for d in leaf.sharding.device_set),
+                     leaf.sharding.shard_shape(leaf.shape)[0]))
                 n = {k: np.asarray(v) for k, v in
                      stack_norms(g.stack, base).items()}
                 k_real = len(next(iter(n.values())))
@@ -375,19 +423,26 @@ def load_reader(name: str):
     folder = os.path.join(BENCH, "metrics")
     if folder not in sys.path:
         sys.path.insert(0, folder)
-    path = os.path.join(folder, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
+    return _load_module(os.path.join(folder, f"{name}.py"),
+                        f"metric_{name}").read
+
+
+def _load_module(path: str, module_name: str):
+    spec = importlib.util.spec_from_file_location(module_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
              seed: int, seconds: float, trace: bool, require_chip: bool = True,
-             cache_dir=CACHE_DIR, log=print) -> dict:
+             cache_dir=CACHE_DIR, log=print, models_dir: str = MODELS) -> dict:
     """One run of one cell; returns the result object.  Raises
     :class:`NoChip` before doing any work when the chips are missing
-    (unless ``require_chip`` is off, as the harness's own tests run)."""
+    (unless ``require_chip`` is off, as the harness's own tests run), and
+    ValueError when the configuration names no model kind of
+    ``models_dir``."""
+    kind = config_kind(config, models_dir)
     t_imports0 = time.perf_counter()
     import jax
     chips = int(cell["chips"])
@@ -401,13 +456,12 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
     from repro.obs import trace as obs
     t_inputs0 = time.perf_counter()
 
-    models = model_dicts(config)
+    models = model_dicts(config, kind)
     fl_seed = int(seed) % SEED_SPAN
     inp = inputs_mod.make_inputs(seed, models[0], traffic, len(models))
     t_engine0 = time.perf_counter()
-    engine, proto = build_engine(config, traffic, inp, fl_seed)
-    globals0 = engine.init_globals()
-    jax.block_until_ready(globals0)
+    engine, proto = build_engine(config, traffic, inp, fl_seed, kind, chips)
+    globals0 = initial_globals(engine)
     t_round0 = time.perf_counter()
 
     tap = RoundOneTap(engine)
@@ -473,6 +527,9 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
         f"round_ends_s={[round(t - win.t_window, 3) for t in win.ends]} "
         f"programs compiled or loaded={n_comp}, of them cache hits={n_hit}, "
         f"in {comp_s:.3f} s; peak_bytes_in_use={peak}")
+    for g, (rows, devs, per) in enumerate(tap.layout):
+        log(f"client stacks: group {g}: {rows} clients over devices {devs}, "
+            f"{per} per device")
 
     metrics, extra = {}, {}
     if trace:
@@ -483,8 +540,8 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
                "round_s": window_s / rounds, "chips": chips,
                "spans": list(state["rec"].spans),
                "compiles": n_comp,
-               "flops": window_flops(models, traffic, inp, proto, fl_seed,
-                                     rounds),
+               "flops": window_flops(kind, models, traffic, inp, proto,
+                                     fl_seed, rounds),
                "peaks": device_peaks(devices[0].device_kind),
                "traffic": traffic, "models": models}
         for m in manifest["per_layer"]:
@@ -514,7 +571,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
     gc.collect()
     t_ref0 = time.perf_counter()
     limits = compare.load_limits()
-    ref_models = [ref_mod.Model(m, traffic) for m in models]
+    ref_models = [ref_mod.Model(m, traffic, kind) for m in models]
     ref = ref_mod.round_one(ref_models, traffic, inp, proto, fl_seed,
                             len(models) > 1)
     nums = compare.numbers(prog, ref)
@@ -545,14 +602,14 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
     return out
 
 
-def window_flops(models, traffic, inp, proto, fl_seed, rounds) -> dict:
+def window_flops(kind, models, traffic, inp, proto, fl_seed, rounds) -> dict:
     """Model FLOPs of the window's rounds (2 .. rounds + 1), by phase."""
     act = flops.cohorts(fl_seed, len(inp.parts),
                         float(traffic["client_fraction"]), rounds + 1)[1:]
     tot = {}
     for a in act:
-        for k, v in flops.round_flops(lambda p: models[p], traffic, inp,
-                                      proto, a).items():
+        for k, v in flops.round_flops(kind, lambda p: models[p], traffic,
+                                      inp, proto, a).items():
             tot[k] = tot.get(k, 0.0) + v
     return tot
 

@@ -13,6 +13,9 @@ sys.path.insert(0, BENCH)
 sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
 
 import flops  # noqa: E402
+import run  # noqa: E402
+
+KIND = run.load_kind("tiny_transformer")
 
 MODEL = {"d_model": 256, "n_layers": 2, "n_heads": 4, "vocab_size": 512,
          "seq_len": 64, "n_classes": 4}
@@ -41,13 +44,13 @@ def test_parameter_count_matches_the_program():
     n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
     emb = MODEL["vocab_size"] * MODEL["d_model"] \
         + MODEL["seq_len"] * MODEL["d_model"]
-    assert flops.non_embedding_params(MODEL) == n - emb
+    assert KIND.non_embedding_params(MODEL) == n - emb
 
 
 def test_forward_flops_against_xla():
     net, params, x = _net()
     xla = _xla_flops(lambda p, x: net.apply(p, x, train=False), params, x)
-    ours = flops.forward_flops_per_token(MODEL) * BATCH * MODEL["seq_len"]
+    ours = KIND.forward_flops_per_token(MODEL) * BATCH * MODEL["seq_len"]
     # XLA also counts the elementwise work (norms, softmax, GELU)
     assert ours <= xla <= 1.06 * ours
 
@@ -61,7 +64,7 @@ def test_train_flops_against_xla():
         return -jnp.mean(jax.nn.log_softmax(logits)[jnp.arange(BATCH), y])
 
     xla = _xla_flops(jax.grad(loss), params, x, y)
-    ours = flops.train_flops_per_token(MODEL) * BATCH * MODEL["seq_len"]
+    ours = flops.train_flops_per_token(KIND, MODEL) * BATCH * MODEL["seq_len"]
     assert 0.97 * ours <= xla <= 1.10 * ours
 
 

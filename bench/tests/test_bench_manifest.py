@@ -118,6 +118,34 @@ def test_per_layer_metrics_have_readers_and_cells(manifest):
                    for m in manifest["per_layer"])
 
 
+def test_every_config_names_a_model_kind(manifest):
+    sys.path.insert(0, BENCH)
+    import run
+    for c in manifest["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            config = json.load(f)
+        kind = run.config_kind(config)
+        for m in run.model_dicts(config, kind):
+            assert {"vocab_size", "seq_len", "n_classes"} <= set(m)
+            assert kind.forward_flops_per_token(m) > 0
+
+
+def test_four_chip_cell_and_its_collectives(manifest):
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    x4 = cells["distilbert.feddf.x4"]
+    assert x4["chips"] == 4 and x4["config"] == "distilbert"
+    with open(os.path.join(BENCH, "traffic", f"{x4['traffic']}.json")) as f:
+        job = json.load(f)
+    active = round(job["client_fraction"] * job["n_clients"])
+    assert active == 16 and active % x4["chips"] == 0
+    metrics = {m["name"]: m for m in manifest["per_layer"]}
+    coll = metrics["collective_ms"]
+    assert coll["workloads"] == ["distilbert.feddf.x4"]
+    assert coll["layer"] == "collectives" and coll["moves"] == "round_s"
+    for name in ("collective_ms.py", "collective_ms.json"):
+        assert os.path.isfile(os.path.join(BENCH, "metrics", name))
+
+
 def test_no_chip_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

@@ -96,3 +96,22 @@ def test_readers_leave_out_what_is_absent(tr):
     assert reader("client_train_ms")(ctx) == pytest.approx(0.342754)
     share = reader("device_idle_share")(ctx)
     assert 0 < share <= 100
+    assert reader("collective_ms")(ctx) is None  # one chip, no collective
+
+
+def test_collective_ms_takes_the_busiest_chip():
+    import collective_ms
+    op = lambda text, a, b: ["jit_counted", text, a, b]
+    gather = "%all-gather.3 = f32[16,4]{1,0} all-gather(f32[4,4]{1,0} %p)"
+    start = "%all-reduce-start.1 = f32[] all-reduce-start(f32[] %x)"
+    done = "%all-reduce-done.1 = f32[] all-reduce-done(f32[] " \
+        "%all-reduce-start.1)"
+    reads = "%fusion.7 = f32[16] fusion(f32[16,4]{1,0} %all-gather.3)"
+    tr = {"devices": {
+        "0": {"modules": [], "ops": [op(gather, 0, 4e6), op(reads, 4e6, 9e6),
+                                     op(start, 10e6, 11e6),
+                                     op(done, 10.5e6, 13e6)]},
+        "1": {"modules": [], "ops": [op(gather, 0, 2e6)]}}, "host": []}
+    # chip 0: 4 ms + the union of 10..11 and 10.5..13 ms, over two rounds
+    assert collective_ms.read({"trace": tr, "rounds": 2}) == \
+        pytest.approx(3.5)
