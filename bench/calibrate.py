@@ -253,6 +253,7 @@ def main(argv=None) -> int:
               "not_compared": compare.not_compared(prog, ref),
               "seconds": time.perf_counter() - t0,
               "reference_seconds": ref.seconds,
+              "reference_live_bytes_max": ref.live_bytes_max,
               "val_history": ref.val_history,
               "worst": compare.worst_leaves(prog, ref)})
         if seed not in args.control_seeds:

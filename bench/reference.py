@@ -11,8 +11,19 @@ runs in float32 at ``highest`` matmul precision; ``dtype="bfloat16"``
 gives the lower-precision control, which runs the same code with
 parameters, optimizer state and activations in bfloat16.
 
-It runs one client, one 512-row block or one step at a time, so that it
-fits beside nothing else on the chip once the program's state is freed.
+It holds one model's training state on the device at a time, so that a
+client that fills a chip can be checked once the program's state is
+freed.  On the host: each group's initial global, every finished
+client's weights, the student's start and its best checkpoint.  On the
+device: the client or student in training with its Adam moments, or the
+data-weighted mean, built leaf by leaf from the host copies, or one
+teacher for its logits; beside them the pool, the bank rows and one
+512-row block of evaluation rows.  Each step adds its new weights and
+moments, a gradient and its activations: the steps donate nothing (a
+donated buffer changes how the TPU rounds some leaves' updates), and the
+loops let a step run ahead of the host only where memory is plentiful
+(``Steps``).  ``RoundOne.live_bytes_max`` is the largest total of live
+device arrays after a step.
 """
 from __future__ import annotations
 
@@ -142,22 +153,28 @@ def client_batches(x: np.ndarray, y: np.ndarray, batch: int, epochs: int,
 
 
 def weighted_mean(trees: List[dict], weights) -> dict:
+    """The weighted mean of host trees, on the device: one leaf's arrays
+    there at a time."""
     w = np.asarray(weights, np.float64)
     w = jnp.asarray(w / w.sum(), jnp.float32)
-    return jax.tree.map(
-        lambda *xs: sum(wi * a.astype(jnp.float32) for wi, a in zip(w, xs)
-                        ).astype(xs[0].dtype), *trees)
+
+    def leaf(*xs):
+        xs = [jnp.asarray(a) for a in xs]
+        return sum(wi * a.astype(jnp.float32) for wi, a in zip(w, xs)
+                   ).astype(xs[0].dtype)
+    return jax.tree.map(leaf, *trees)
 
 
 def leaf_norms(tree: dict, base: Optional[dict] = None) -> Dict[str, float]:
-    """Per-leaf L2 norm of ``tree`` (minus ``base``), keyed by leaf path."""
+    """Per-leaf L2 norm of ``tree`` (minus ``base``), keyed by leaf path,
+    on the device; a host tree goes there one leaf at a time."""
     out = {}
     base_leaves = (jax.tree_util.tree_leaves_with_path(base)
                    if base is not None else None)
     for i, (path, a) in enumerate(jax.tree_util.tree_leaves_with_path(tree)):
-        a = a.astype(jnp.float32)
+        a = jnp.asarray(a).astype(jnp.float32)
         if base_leaves is not None:
-            a = a - base_leaves[i][1].astype(jnp.float32)
+            a = a - jnp.asarray(base_leaves[i][1]).astype(jnp.float32)
         out[jax.tree_util.keystr(path)] = float(jnp.sqrt(jnp.sum(a * a)))
     return out
 
@@ -178,6 +195,34 @@ class RoundOne:
     ens_acc: Optional[float]
     seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     val_history: List[list] = dataclasses.field(default_factory=list)
+    live_bytes_max: int = 0  # live device bytes, largest after a step
+
+
+class Steps:
+    """Paces a loop's steps and keeps the largest total of the process's
+    live device arrays seen between them.  Where a training state
+    (weights and two moments, ``state_bytes``) takes under an eighth of
+    the device's memory, the host waits for the step before the one it
+    has just dispatched, so that the chip has the next step queued;
+    otherwise it waits for each step, as a step queued ahead holds its
+    outputs beside the running one's."""
+
+    def __init__(self, state_bytes: int):
+        limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        self.ahead = limit is None or 8 * state_bytes < limit
+        self.live_max = 0
+        self._last = None
+
+    def settle(self, marker) -> None:
+        """``marker``: a small output of the step just dispatched (all of
+        a step's outputs are ready at once)."""
+        if not self.ahead:
+            jax.block_until_ready(marker)
+        elif self._last is not None:
+            jax.block_until_ready(self._last)
+        self._last = marker
+        self.live_max = max(self.live_max,
+                            sum(a.nbytes for a in jax.live_arrays()))
 
 
 def round_one(models: List[Model], job: dict, inputs, proto: List[int],
@@ -201,8 +246,10 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
     active = np.random.default_rng(seed).choice(n_clients, size=n_active,
                                                 replace=False)
     mult = 99991 if heterogeneous else 100_003
-    g0 = [m.init(seed + p if heterogeneous else seed)
+    g0 = [jax.device_get(m.init(seed + p if heterogeneous else seed))
           for p, m in enumerate(models)]
+    waits = Steps(3 * max(sum(a.nbytes for a in jax.tree.leaves(g))
+                          for g in g0))
     feddf = job["strategy"] == "feddf"
     batch, epochs = int(job["local_batch_size"]), int(job["local_epochs"])
 
@@ -213,7 +260,7 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
         rows = []
         for k in [int(k) for k in active if proto[int(k)] == p]:
             part = inputs.parts[k]
-            params = g0[p]
+            params = jax.device_put(g0[p])
             mom = jax.tree.map(jnp.zeros_like, params)
             vel = jax.tree.map(jnp.zeros_like, params)
             for i, (xb, yb) in enumerate(client_batches(
@@ -222,14 +269,15 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
                 params, mom, vel, norms = m.client_step(
                     params, mom, vel, jnp.asarray(xb), jnp.asarray(yb),
                     jnp.float32(i))
+                waits.settle(norms)
                 if first_grad is None:
                     first_grad = {
                         jax.tree_util.keystr(path): float(g) for path, g in
                         jax.tree_util.tree_leaves_with_path(norms)}
             rows.append(leaf_norms(params, g0[p]))
-            stacks[p].append(params)
+            stacks[p].append(jax.device_get(params))
             weights[p].append(float(len(part)))
-            del mom, vel
+            del params, mom, vel
         clients.append(rows)
     lap("clients")
 
@@ -238,7 +286,7 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
         tot = None
         for p, m in enumerate(models):
             for params in stacks[p]:
-                lg = m.predict(params, inputs.test.x)
+                lg = m.predict(jax.device_put(params), inputs.test.x)
                 tot = lg if tot is None else tot + lg
         ens_acc = float(np.mean(np.argmax(tot, -1) == inputs.test.y))
 
@@ -250,7 +298,7 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
         n_teach = 0
         for p, m in enumerate(models):
             for params in stacks[p]:
-                bank += m.predict(params, pool)
+                bank += m.predict(jax.device_put(params), pool)
                 n_teach += 1
         bank = (bank / n_teach).astype(np.float32)
         lap("bank")
@@ -261,16 +309,22 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
         if not stacks[p]:
             fused.append(leaf_norms(g0[p], g0[p]))
             distilled.append(False)
-            test_acc.append(m.accuracy(g0[p], inputs.test.x, inputs.test.y))
-            val_acc.append(m.accuracy(g0[p], inputs.val.x, inputs.val.y))
+            start = jax.device_put(g0[p])
+            test_acc.append(m.accuracy(start, inputs.test.x, inputs.test.y))
+            val_acc.append(m.accuracy(start, inputs.val.x, inputs.val.y))
             pre_acc.append(None)
+            del start
             continue
         avg = weighted_mean(stacks[p], weights[p])
         if feddf:
             pre_acc.append(None if heterogeneous else
                            m.accuracy(avg, inputs.test.x, inputs.test.y))
-            out, hist, first = _distill(m, avg, inputs, bank, job,
-                                        seed + t + (p if heterogeneous else 0))
+            start = jax.device_get(avg)
+            del avg  # the student starts from the host copy
+            out, hist, first = _distill(m, start, inputs, bank, job,
+                                        seed + t + (p if heterogeneous else 0),
+                                        waits)
+            out = jax.device_put(out)
             history.append(hist)
             chunks.append(first)
         else:
@@ -280,25 +334,28 @@ def round_one(models: List[Model], job: dict, inputs, proto: List[int],
         distilled.append(feddf)
         test_acc.append(m.accuracy(out, inputs.test.x, inputs.test.y))
         val_acc.append(m.accuracy(out, inputs.val.x, inputs.val.y))
+        del out
     lap("fusion_and_evaluation")
     return RoundOne(clients=clients, first_grad=first_grad or {}, bank=bank,
                     fused=fused, distilled=distilled, chunks=chunks,
                     test_acc=test_acc, val_acc=val_acc, pre_acc=pre_acc,
-                    ens_acc=ens_acc, seconds=seconds, val_history=history)
+                    ens_acc=ens_acc, seconds=seconds, val_history=history,
+                    live_bytes_max=waits.live_max)
 
 
 def _distill(m: Model, student: dict, inputs, bank: np.ndarray, job: dict,
-             seed: int):
+             seed: int, waits: Steps):
     """Adam on KL(softmax(bank row) || softmax(student)) over batches drawn
     from the pool, checking validation accuracy every ``eval_every`` steps
     and keeping the best checkpoint (strictly better replaces; the
-    starting student is never kept).  Returns (best, the validation
-    accuracies, the first check's per-leaf change from ``student`` and
-    gradient norm as Adam's bias-corrected second moment holds it)."""
+    starting student is never kept).  ``student`` and the best checkpoint
+    are host trees.  Returns (best; the validation accuracies; the first
+    check's per-leaf change from ``student`` and gradient norm as Adam's
+    bias-corrected second moment holds it)."""
     steps, every = int(job["distill_steps"]), int(job["eval_every"])
     pool = jnp.asarray(inputs.pool)
     rows = jnp.asarray(bank)
-    params = student
+    params = jax.device_put(student)
     mom = jax.tree.map(jnp.zeros_like, params)
     vel = jax.tree.map(jnp.zeros_like, params)
     key = jax.random.PRNGKey(seed)
@@ -306,6 +363,7 @@ def _distill(m: Model, student: dict, inputs, bank: np.ndarray, job: dict,
     for step in range(steps):
         params, mom, vel, key = m.distill_step(
             params, mom, vel, key, jnp.float32(step), pool, rows)
+        waits.settle(key)
         if step + 1 == every:
             corr = 1.0 - ADAM_B2 ** every
             first = {"change": leaf_norms(params, student),
@@ -317,5 +375,5 @@ def _distill(m: Model, student: dict, inputs, bank: np.ndarray, job: dict,
             acc = m.accuracy(params, inputs.val.x, inputs.val.y)
             hist.append([step + 1, acc])
             if acc > best_acc:
-                best, best_acc = params, acc
+                best, best_acc = jax.device_get(params), acc
     return best, hist, first

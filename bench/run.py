@@ -509,8 +509,7 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
     window_s = win.ends[-1] - win.t_window
     setup_s = win.t_window - T_START
     n_comp, n_hit, comp_s = watch.between(win.t_window, win.ends[-1])
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devices)
+    peak = peak_bytes(devices)
     round_logs = [win.logs[t] for t in sorted(win.logs)]
     failed = sum(1 for logs in round_logs[1:]
                  if any(not np.isfinite(l.test_acc) or not l.fused
@@ -576,6 +575,10 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
                             len(models) > 1)
     nums = compare.numbers(prog, ref)
     correct = compare.judge(nums, limits)
+    log(f"reference: live_bytes_max={ref.live_bytes_max} "
+        f"peak_bytes_in_use={peak_bytes(devices)} (the process's, after "
+        f"the replay; "
+        f"the window's {peak})")
     log(f"reference: {time.perf_counter() - t_ref0:.3f} s "
         f"({ref.seconds}); val accuracies at its checkpoints "
         f"{ref.val_history}; round 1 test/val/pre-distillation/ensemble "
@@ -600,6 +603,12 @@ def run_cell(cell: dict, config: dict, traffic: dict, manifest: dict, *,
     out["checks"] = compare.as_json(nums, limits)
     out["_check_lines"] = compare.lines(nums, limits)
     return out
+
+
+def peak_bytes(devices) -> int:
+    """The process's peak device bytes on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def window_flops(kind, models, traffic, inp, proto, fl_seed, rounds) -> dict:

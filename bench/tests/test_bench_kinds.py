@@ -263,3 +263,11 @@ def test_four_chip_cell_without_exchange_is_not_correct(four_chip_runs):
     assert not out["correct"], out["checks"]
     checks = out["checks"]
     assert checks["bank_logits"]["value"] > checks["bank_logits"]["limit"]
+
+
+def test_run_logs_what_the_replay_held(four_chip_runs):
+    lines = four_chip_runs["None"]["lines"]
+    held = [l for l in lines if l.startswith("reference: live_bytes_max=")]
+    assert len(held) == 1
+    n = int(held[0].split("=")[1].split()[0])
+    assert n > 0 and "peak_bytes_in_use=" in held[0]

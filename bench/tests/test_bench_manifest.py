@@ -146,6 +146,41 @@ def test_four_chip_cell_and_its_collectives(manifest):
         assert os.path.isfile(os.path.join(BENCH, "metrics", name))
 
 
+def test_c8_cell_is_the_cohort_one_chip_holds(manifest):
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    c8 = cells["distilbert.feddf.c8"]
+    assert c8["chips"] == 1 and c8["config"] == "distilbert"
+    jobs = {}
+    for name in (c8["traffic"], cells["distilbert.feddf"]["traffic"]):
+        with open(os.path.join(BENCH, "traffic", f"{name}.json")) as f:
+            jobs[name] = json.load(f)
+    job, base = jobs[c8["traffic"]], jobs["feddf"]
+    assert {k for k in set(job) | set(base) if job.get(k) != base.get(k)} \
+        == {"about", "n_clients", "n_samples"}
+    assert round(job["client_fraction"] * job["n_clients"]) == 8
+    rows = lambda j: j["n_samples"] * (1 - j["val_frac"] - j["test_frac"]) \
+        / j["n_clients"]
+    assert rows(job) == pytest.approx(rows(base))  # 840 rows a client
+    for m in manifest["per_layer"]:
+        assert (c8["name"] in m["workloads"]) == (m["name"] !=
+                                                    "collective_ms")
+
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_exits_without_a_chip(cell):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload", cell,
+         "--seed", "2147483999", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0 and "run.py: the cell needs" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
 def test_no_chip_no_result(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
