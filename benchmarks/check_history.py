@@ -9,7 +9,7 @@ each bench invocation — identical keys, identical thresholds, so
 migrating the workflow onto this checker loosened nothing.
 
     PYTHONPATH=src python -m benchmarks.check_history \
-        --require driver --require bucketing
+        --require driver --require population
 
 ``--require`` fails the run when a bench has no record at all (without
 it, only benches present in the history are gated — useful locally
@@ -68,20 +68,6 @@ def _distill_quant(m: dict, machine: dict) -> GateResult:
     if len(m["roofline_records"]) != 4:  # fused/unfused x dtype
         errs.append(f"expected 4 roofline records, "
                     f"got {len(m['roofline_records'])}")
-    return errs, []
-
-
-def _bucketing(m: dict, machine: dict) -> GateResult:
-    errs = []
-    if not m["waste_reduction_x"] >= 2.0:
-        errs.append(f"padding-waste reduction regressed: "
-                    f"{m['waste_reduction_x']}")
-    if m["trajectory_equal"] is not True:
-        errs.append("bucketed trajectory drifted from unbucketed "
-                    "(must be exact)")
-    if not m["marginal_steps_per_s_speedup"] >= 1.1:
-        errs.append(f"bucketing speedup regressed: "
-                    f"{m['marginal_steps_per_s_speedup']}")
     return errs, []
 
 
@@ -186,7 +172,6 @@ def _paper(m: dict, machine: dict) -> GateResult:
 GATES: Dict[str, Callable[[dict, dict], GateResult]] = {
     "distill": _distill,
     "distill_quant": _distill_quant,
-    "bucketing": _bucketing,
     "driver": _driver,
     "population": _population,
     "robustness": _robustness,

@@ -22,8 +22,8 @@ from repro.api.registries import (TaskBundle, available_models,
                                   get_task, register_model,
                                   register_quantizer, register_source,
                                   register_task)
-from repro.api.spec import (BucketSpec, CohortSpec, DistSpec, DriverSpec,
-                            ExperimentSpec, FaultSpec, FusionSpec,
+from repro.api.spec import (CohortSpec, DistSpec, DriverSpec, ExperimentSpec,
+                            FaultSpec, FusionSpec,
                             ModelSpec, ObsSpec, PartitionSpec,
                             PopulationSpec, PrivacySpec, ShardingSpec,
                             SourceSpec, StrategySpec, TaskSpec,
@@ -33,7 +33,7 @@ __all__ = [
     "Experiment", "RoundEvent", "RunResult",
     "ExperimentSpec", "TaskSpec", "PartitionSpec", "CohortSpec",
     "ModelSpec", "SourceSpec", "StrategySpec", "FusionSpec",
-    "PrivacySpec", "ShardingSpec", "DriverSpec", "BucketSpec",
+    "PrivacySpec", "ShardingSpec", "DriverSpec",
     "PopulationSpec", "TrafficSpec", "FaultSpec", "ObsSpec", "DistSpec",
     "TaskBundle", "register_task", "register_model", "register_source",
     "register_quantizer", "get_task", "get_model", "get_source",

@@ -34,8 +34,8 @@ from repro.api.registries import (TaskBundle, get_model, get_quantizer,
                                   get_source, get_task)
 from repro.api.spec import ExperimentSpec
 from repro.checkpoint import io as ckpt
-from repro.core.engine import (_UNSET, BucketConfig, FLConfig, FLResult,
-                               RoundLog, run_rounds)
+from repro.core.engine import (_UNSET, FLConfig, FLResult, RoundLog,
+                               run_rounds)
 from repro.core.feddf import FusionConfig
 from repro.core.nets import Net
 from repro.dist.config import DistConfig
@@ -305,8 +305,6 @@ def to_fl_config(spec: ExperimentSpec) -> FLConfig:
         target_accuracy=spec.target_accuracy,
         dp_clip=spec.privacy.clip,
         dp_noise_multiplier=spec.privacy.noise_multiplier,
-        bucketing=BucketConfig(kind=spec.bucket.kind,
-                               max_buckets=spec.bucket.max_buckets),
         population=PopulationConfig(
             size=spec.population.size,
             sampler=spec.population.sampler,

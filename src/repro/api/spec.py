@@ -249,32 +249,6 @@ class ShardingSpec:
 
 
 @dataclasses.dataclass
-class BucketSpec:
-    """Step-count bucketing of the round engine's client axis
-    (docs/bucketing.md).
-
-    ``kind``: ``none`` (pad every client of a prototype group to the
-    group-wide maximum scan length — the historic path), ``pow2``
-    (power-of-two scan capacities) or ``quantile`` (capacities at
-    step-count quantiles).  ``max_buckets`` bounds the per-run compile
-    count (at most buckets x prototypes client-update programs).
-    Bucketing never changes a trajectory — it only regroups the vmap
-    axis — but on skewed Dirichlet splits it removes most of the masked
-    no-op padding steps."""
-
-    kind: str = "none"               # none | pow2 | quantile
-    max_buckets: int = 4
-
-    def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "BucketSpec":
-        _check_keys(cls, d)
-        return cls(**d)
-
-
-@dataclasses.dataclass
 class TrafficSpec:
     """Virtual-time client traffic model (docs/population.md).
 
@@ -515,7 +489,6 @@ class ExperimentSpec:
     privacy: PrivacySpec = dataclasses.field(default_factory=PrivacySpec)
     sharding: ShardingSpec = dataclasses.field(default_factory=ShardingSpec)
     driver: DriverSpec = dataclasses.field(default_factory=DriverSpec)
-    bucket: BucketSpec = dataclasses.field(default_factory=BucketSpec)
     population: PopulationSpec = dataclasses.field(
         default_factory=PopulationSpec)
     faults: FaultSpec = dataclasses.field(default_factory=FaultSpec)
@@ -544,7 +517,6 @@ class ExperimentSpec:
             "privacy": self.privacy.to_dict(),
             "sharding": self.sharding.to_dict(),
             "driver": self.driver.to_dict(),
-            "bucket": self.bucket.to_dict(),
             "population": self.population.to_dict(),
             "faults": self.faults.to_dict(),
             "obs": self.obs.to_dict(),
@@ -567,8 +539,7 @@ class ExperimentSpec:
         nested = {"task": TaskSpec, "partition": PartitionSpec,
                   "cohort": CohortSpec, "strategy": StrategySpec,
                   "privacy": PrivacySpec, "sharding": ShardingSpec,
-                  "driver": DriverSpec, "bucket": BucketSpec,
-                  "population": PopulationSpec, "faults": FaultSpec,
+                  "driver": DriverSpec, "population": PopulationSpec, "faults": FaultSpec,
                   "obs": ObsSpec, "dist": DistSpec}
         for key, sub in nested.items():
             if key in d and isinstance(d[key], dict):
@@ -654,14 +625,6 @@ class ExperimentSpec:
             if bad:
                 raise ValueError(
                     f"fusion.batch_sizes must all be >= 1, got {bad}")
-        if self.bucket.kind not in BUCKET_KINDS:
-            raise ValueError(
-                f"bucket.kind must be one of {BUCKET_KINDS}, got "
-                f"{self.bucket.kind!r}")
-        if self.bucket.max_buckets < 1:
-            raise ValueError(
-                f"bucket.max_buckets must be >= 1, got "
-                f"{self.bucket.max_buckets}")
 
         from repro.drivers import get_driver
         get_driver(self.driver.kind)  # unknown kinds fail before any work

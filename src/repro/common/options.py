@@ -17,7 +17,7 @@ BANK_DTYPES = ("float32", "bfloat16", "int8", "fp8_e4m3")
 QUANTIZED_BANK_DTYPES = ("int8", "fp8_e4m3")
 FUSED_KERNEL_MODES = (True, False, "auto")
 
-# step-count bucketing of the round engine's client axis
+# capacity bucketing of heterogeneous FedDF's distillation batch sizes
 # (core/client.py:bucket_capacities, docs/bucketing.md)
 BUCKET_KINDS = ("none", "pow2", "quantile")
 

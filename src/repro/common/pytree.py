@@ -58,7 +58,7 @@ def tree_take(tree: Pytree, idx) -> Pytree:
 
 def tree_cat(trees: Sequence[Pytree]) -> Pytree:
     """Concatenate stacked pytrees along the leading (client) axis —
-    the bucketed round engine's per-bucket stacks re-join through this."""
+    the buffered-async driver's buffered uploads join through this."""
     if len(trees) == 1:
         return trees[0]
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *trees)

@@ -7,7 +7,6 @@ from repro.core.feddf import (FusionConfig, avg_logits_kl,
 from repro.core.logit_bank import (PERSISTENT_BANK, TEACHER_FORWARDS,
                                    LogitBank, bank_for_fusion,
                                    build_logit_bank, resolve_bank)
-from repro.core.engine import BucketConfig
 from repro.core.server import (FLConfig, FLResult, RoundLog, run_federated,
                                run_federated_heterogeneous, run_rounds)
 from repro.core.strategies import (ServerStrategy, available_strategies,

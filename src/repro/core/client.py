@@ -1,21 +1,21 @@
 """Client-side local training (Algorithm 2).
 
-One jit-compiled ``lax.scan`` runs all local steps of a round: the batches
-for every epoch are materialised as arrays [n_steps, B, ...] outside and
-scanned inside — orders of magnitude faster than a python loop on CPU, and
-the compiled function is reused across clients and rounds (same shapes).
+The batches for every epoch are materialised as arrays [n_steps, B, ...]
+outside and looped over inside one jit-compiled program — orders of
+magnitude faster than a python loop per step, and the compiled function
+is reused across clients and rounds (same shapes).
 
 Two entry points:
 
 * :func:`make_local_update` — one client per call (the original path, kept
   for tests/benchmarks and as the numerical reference).
-* :func:`make_batched_local_update` — ALL active clients of a round at
-  once: batch tensors are stacked to [K, n_steps, B, ...] and one jitted
-  ``vmap``-over-clients ``lax.scan`` trains every client in a single
-  compiled program (see docs/round_engine.md).  FedProx anchoring,
-  quantized forwards, and DP privatization of the uploads all run inside
-  the jitted path; an optional mesh shards the leading client axis across
-  devices (``shard_map``) so clients train data-parallel.
+* :func:`make_batched_local_update` — ALL active clients of a round in one
+  compiled program: batch tensors are stacked to [K, n_steps, B, ...] and
+  the clients train one after another, each for exactly its own step
+  count (see docs/round_engine.md).  FedProx anchoring, quantized
+  forwards, and DP privatization of the uploads all run inside the jitted
+  path; an optional mesh shards the leading client axis across devices
+  (``shard_map``) so each device trains its share of the clients.
 
 Supports: plain SGD (FedAvg), proximal term (FedProx, Appendix B), arbitrary
 optimizers (the paper's Adam-local-training ablation, Table 6), BatchNorm
@@ -25,7 +25,7 @@ running-stats maintenance, and a quantize transform for low-bit clients
 from __future__ import annotations
 
 import weakref
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,17 +37,48 @@ from repro.core.nets import Net
 from repro.optim.optimizers import Optimizer, apply_updates
 
 # Counts TRACES of the batched client update (the python side effect only
-# fires when jax re-traces, i.e. compiles a new program) — the bucketing
-# tests' evidence that compile count stays bounded by buckets x prototypes
-# per run instead of growing with rng-driven cohort shapes.  Registered
-# in the unified metrics registry; this module-level alias keeps the
-# historic reset()/.count interface for tests.
+# fires when jax re-traces, i.e. compiles a new program) — the tests'
+# evidence that the compile count stays at one per prototype per run
+# instead of growing with rng-driven cohort shapes.  Registered in the
+# unified metrics registry; this module-level alias keeps the historic
+# reset()/.count interface for tests.
 CLIENT_COMPILES = REGISTRY.counter("core.client.compiles")
+# Client-steps the cohorts hold (``RoundBatches.real_steps``); the
+# engine's ``train_clients`` adds each round's.
+REAL_STEPS = REGISTRY.counter("core.client.real_steps")
 
 
 def softmax_xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, labels[..., None], -1))
+
+
+def _local_step(net: Net, opt: Optimizer, prox_mu: float,
+                quantize: Optional[Callable]):
+    """One local step: ``step(params, state, i, x, y, anchor, mask) ->
+    (params, state)``, shared by both entry points."""
+
+    def loss_fn(params, x, y, anchor):
+        p = quantize(params) if quantize is not None else params
+        logits, stats = net.apply_with_stats(p, x)
+        loss = softmax_xent(logits, y)
+        if prox_mu > 0.0:
+            loss = loss + 0.5 * prox_mu * tree_sq_dist(params, anchor)
+        return loss, stats
+
+    def step(params, state, i, x, y, anchor, mask):
+        grads, stats = jax.grad(loss_fn, has_aux=True)(params, x, y, anchor)
+        grads = jax.tree.map(lambda g, m: g if m else jnp.zeros_like(g),
+                             grads, mask)
+        deltas, state = opt.update(grads, state, params, i)
+        new_params = apply_updates(params, deltas)
+        # take BN running stats from the forward pass (non-trainable)
+        new_params = jax.tree.map(
+            lambda new, st, m: new if m else st.astype(new.dtype),
+            new_params, stats, mask)
+        return new_params, state
+
+    return step
 
 
 def make_local_update(net: Net, opt: Optimizer, *, prox_mu: float = 0.0,
@@ -57,44 +88,29 @@ def make_local_update(net: Net, opt: Optimizer, *, prox_mu: float = 0.0,
     ``anchor`` is the round's global model (FedProx pulls towards it; pass
     the initial params when prox_mu == 0, it is ignored).
     """
-
-    def loss_fn(params, x, y):
-        p = quantize(params) if quantize is not None else params
-        logits, stats = net.apply_with_stats(p, x)
-        loss = softmax_xent(logits, y)
-        return loss, stats
+    step = _local_step(net, opt, prox_mu, quantize)
 
     @jax.jit
     def run(params, xb, yb, anchor):
-        state = opt.init(params)
         mask = net.trainable_mask(params)
 
-        def step(carry, batch):
+        def body(carry, batch):
             params, state, i = carry
-            x, y = batch
+            params, state = step(params, state, i, *batch, anchor, mask)
+            return (params, state, i + 1), None
 
-            def total_loss(p):
-                loss, stats = loss_fn(p, x, y)
-                if prox_mu > 0.0:
-                    loss = loss + 0.5 * prox_mu * tree_sq_dist(p, anchor)
-                return loss, stats
-
-            grads, stats = jax.grad(total_loss, has_aux=True)(params)
-            grads = jax.tree.map(lambda g, m: g if m else jnp.zeros_like(g),
-                                 grads, mask)
-            deltas, state = opt.update(grads, state, params, i)
-            new_params = apply_updates(params, deltas)
-            # take BN running stats from the forward pass (non-trainable)
-            new_params = jax.tree.map(
-                lambda new, st, m: new if m else st.astype(new.dtype),
-                new_params, stats, mask)
-            return (new_params, state, i + 1), None
-
-        (params, _, _), _ = jax.lax.scan(step, (params, state, jnp.int32(0)),
-                                         (xb, yb))
+        (params, _, _), _ = jax.lax.scan(
+            body, (params, opt.init(params), jnp.int32(0)), (xb, yb))
         return params
 
     return run
+
+
+def trip_counts(step_mask):
+    """Steps each client trains: the true entries of its prefix step mask
+    ([..., n] -> [...]).  :func:`make_batched_local_update` loops exactly
+    this many times per client."""
+    return step_mask.sum(axis=-1, dtype=np.int32)
 
 
 def make_batched_local_update(net: Net, opt: Optimizer, *,
@@ -104,21 +120,26 @@ def make_batched_local_update(net: Net, opt: Optimizer, *,
                               dp_noise_multiplier: float = 0.0,
                               mesh=None, client_axis: str = "data",
                               donate_batches: bool = False):
-    """Vectorized local training for all K active clients of a round.
+    """Local training for all K active clients of a round, one program.
 
     Returns jit'd ``fn(params, xb [K,n,B,...], yb [K,n,B], anchor,
     step_mask [K,n], dp_keys [K,2]) -> stacked params [K, ...]``.
 
-    ``step_mask`` pads clients with fewer local steps: masked steps leave
-    params, optimizer state, and the step counter untouched, so each
-    client's trajectory is numerically identical to the sequential
-    :func:`make_local_update` run on its own (unpadded) batches.
+    The clients train one after another (``lax.map`` over the client
+    axis), each in a ``while_loop`` that runs exactly its own step count,
+    :func:`trip_counts` of its prefix ``step_mask`` (the layout
+    :func:`build_batched_batches` builds).  The padded tail of a short
+    client, and a padded client slot with an all-False mask, never run on
+    the device, so each client's trajectory is the sequential
+    :func:`make_local_update` run on its own (unpadded) batches, and only
+    one client's training state is live at a time.  The shapes, and so
+    the compiled program, stay fixed for any cohort's step counts.
 
     When ``dp_clip`` is set, every client's upload is clipped + noised
     (``core/privacy.py``) inside the same jitted program, keyed per client
     by ``dp_keys``.  With a ``mesh``, the leading client axis is sharded
-    over ``client_axis`` via ``shard_map`` (K must divide the axis size)
-    so clients train data-parallel across devices.
+    over ``client_axis`` via ``shard_map`` (K must divide the axis size):
+    each device loops over its own clients.
 
     ``donate_batches=True`` donates the per-round scratch tensors
     (``xb``/``yb``/``step_mask``/``dp_keys``) so XLA reuses their (large)
@@ -128,43 +149,21 @@ def make_batched_local_update(net: Net, opt: Optimizer, *,
     to every group and reads it again after training.  Callers that reuse
     their batch arrays across calls (benchmarks) must keep the default.
     """
+    step = _local_step(net, opt, prox_mu, quantize)
 
-    def loss_fn(params, x, y):
-        p = quantize(params) if quantize is not None else params
-        logits, stats = net.apply_with_stats(p, x)
-        loss = softmax_xent(logits, y)
-        return loss, stats
-
-    def one_client(params, xb, yb, anchor, step_mask, dp_key):
-        state = opt.init(params)
+    def one_client(params, anchor, xb, yb, step_mask, dp_key):
         mask = net.trainable_mask(params)
+        n = trip_counts(step_mask)
 
-        def step(carry, batch):
+        def body(carry):
             params, state, i = carry
-            x, y, valid = batch
+            params, state = step(params, state, i, xb[i], yb[i], anchor,
+                                 mask)
+            return params, state, i + 1
 
-            def total_loss(p):
-                loss, stats = loss_fn(p, x, y)
-                if prox_mu > 0.0:
-                    loss = loss + 0.5 * prox_mu * tree_sq_dist(p, anchor)
-                return loss, stats
-
-            grads, stats = jax.grad(total_loss, has_aux=True)(params)
-            grads = jax.tree.map(lambda g, m: g if m else jnp.zeros_like(g),
-                                 grads, mask)
-            deltas, new_state = opt.update(grads, state, params, i)
-            new_params = apply_updates(params, deltas)
-            new_params = jax.tree.map(
-                lambda new, st, m: new if m else st.astype(new.dtype),
-                new_params, stats, mask)
-            # padded steps are no-ops: keep the whole carry unchanged
-            keep = lambda n, o: jnp.where(valid, n, o)
-            params = jax.tree.map(keep, new_params, params)
-            state = jax.tree.map(keep, new_state, state)
-            return (params, state, jnp.where(valid, i + 1, i)), None
-
-        (params, _, _), _ = jax.lax.scan(step, (params, state, jnp.int32(0)),
-                                         (xb, yb, step_mask))
+        params, _, _ = jax.lax.while_loop(
+            lambda carry: carry[2] < n, body,
+            (params, opt.init(params), jnp.int32(0)))
         if dp_clip is not None:
             from repro.core.privacy import privatize_update
             params = privatize_update(anchor, params, clip=dp_clip,
@@ -172,18 +171,20 @@ def make_batched_local_update(net: Net, opt: Optimizer, *,
                                       key=dp_key)
         return params
 
-    batched = jax.vmap(one_client, in_axes=(None, 0, 0, None, 0, 0))
+    def clients(params, xb, yb, anchor, step_mask, dp_keys):
+        return jax.lax.map(lambda c: one_client(params, anchor, *c),
+                           (xb, yb, step_mask, dp_keys))
 
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
         rep, cl = P(), P(client_axis)
-        batched = jax.shard_map(batched, mesh=mesh,
+        clients = jax.shard_map(clients, mesh=mesh,
                                 in_specs=(rep, cl, cl, rep, cl, cl),
                                 out_specs=cl, check_vma=False)
 
     def counted(params, xb, yb, anchor, step_mask, dp_keys):
         CLIENT_COMPILES.add(1)  # trace-time side effect: counts compiles
-        return batched(params, xb, yb, anchor, step_mask, dp_keys)
+        return clients(params, xb, yb, anchor, step_mask, dp_keys)
 
     from repro.common.sharding import donation_supported
     donate = ((1, 2, 4, 5) if donate_batches and donation_supported()
@@ -224,9 +225,9 @@ def build_batched_batches(x: np.ndarray, y: np.ndarray,
 
     Returns ``(xb [K,n,B,...], yb [K,n,B], step_mask [K,n])``.  Clients with
     fewer steps than ``n_steps`` (or the round maximum) are zero-padded at
-    the END and masked out, preserving step-for-step equivalence with the
-    sequential path.  Pass a fixed ``n_steps`` (max over ALL clients) so
-    every round reuses one compiled program.
+    the END and masked out (a prefix mask: the batched update runs only
+    the masked-in steps).  Pass a fixed ``n_steps`` (max over ALL clients)
+    so every round reuses one compiled program.
     """
     per = [build_batches(x[idx], y[idx], batch_size, epochs, seed=s)
            for idx, s in zip(parts, seeds)]
@@ -246,22 +247,19 @@ def build_batched_batches(x: np.ndarray, y: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# step-count bucketing (docs/bucketing.md)
+# capacity bucketing (the distillation axis, core/feddf.py)
 #
-# Padding every client of a prototype group to the group-wide maximum scan
-# length is what makes ONE compiled program per prototype possible, but on
-# a skewed Dirichlet split the largest client can have 10-50x the steps of
-# the median, so most vmapped lanes burn masked no-op FLOPs.  Bucketing
-# partitions the clients into a small FIXED set of step capacities
-# (computed once per run from the static per-client step counts) and runs
-# one vmapped scan per bucket: a 10-step client no longer scans 500 padded
-# steps, and the compile count stays bounded by buckets x prototypes.
+# Heterogeneous FedDF gives each prototype group its own distillation batch
+# size; padding every group to the largest keeps ONE compiled chunk
+# program.  Bucketing the sizes into a small FIXED set of capacities
+# (computed once per run) pads each group only to its bucket's capacity,
+# and the compile count stays bounded by the number of buckets.
 # ---------------------------------------------------------------------------
 
 
 def bucket_capacities(step_counts: Sequence[int], kind: str,
                       max_buckets: int = 4) -> List[int]:
-    """The run-fixed set of scan-length capacities for one prototype group.
+    """The run-fixed set of padded capacities for a list of sizes.
 
     Returns an ascending list whose LAST entry is exactly
     ``max(step_counts)`` (so a single bucket reproduces the unbucketed
@@ -269,11 +267,11 @@ def bucket_capacities(step_counts: Sequence[int], kind: str,
 
     ``pow2``      capacities are powers of two clipped at the maximum; when
                   that yields more than ``max_buckets``, the LARGEST
-                  capacities are kept (small clients fall into bigger
-                  buckets — more padding, never a truncated scan).
+                  capacities are kept (small sizes fall into bigger
+                  buckets — more padding, never a truncated one).
     ``quantile``  capacities at ``max_buckets`` evenly-spaced quantiles of
-                  the step-count distribution (always including the max).
-    ``none``      the single group-wide maximum: today's padded path.
+                  the sizes' distribution (always including the max).
+    ``none``      the single maximum: everything padded to it.
     """
     steps = sorted(int(s) for s in step_counts)
     if not steps:
@@ -296,44 +294,13 @@ def bucket_capacities(step_counts: Sequence[int], kind: str,
 
 def assign_buckets(step_counts: Sequence[int],
                    caps: Sequence[int]) -> np.ndarray:
-    """Index of the smallest capacity holding each client's step count."""
+    """Index of the smallest capacity holding each size."""
     idx = np.searchsorted(np.asarray(caps), np.asarray(step_counts),
                           side="left")
     if (idx >= len(caps)).any():
         raise ValueError(f"step count(s) exceed the largest bucket "
                          f"capacity {caps[-1]}")
     return idx
-
-
-def build_bucketed_batches(
-        x: np.ndarray, y: np.ndarray, parts: Sequence[np.ndarray],
-        batch_size: int, epochs: int, seeds: Sequence[int],
-        caps: Sequence[int],
-) -> List[Tuple[int, np.ndarray, np.ndarray, np.ndarray, np.ndarray]]:
-    """Bucketed variant of :func:`build_batched_batches`.
-
-    Partitions the clients over the run-fixed ``caps`` (ascending scan
-    capacities, see :func:`bucket_capacities`) and stacks each bucket's
-    scanned batches separately, padded only to the BUCKET's capacity.
-
-    Returns one ``(bucket_index, positions, xb, yb, step_mask)`` tuple per
-    non-empty bucket, where ``positions`` are the clients' indices into
-    ``parts`` — each client's batch stream is byte-identical to the one
-    :func:`build_batched_batches` builds (same per-client seeds, same
-    order), only the zero-padded tail is shorter.
-    """
-    steps = [n_local_steps(len(idx), batch_size, epochs) for idx in parts]
-    which = assign_buckets(steps, caps)
-    out = []
-    for b in range(len(caps)):
-        pos = np.flatnonzero(which == b)
-        if not len(pos):
-            continue
-        xb, yb, mask = build_batched_batches(
-            x, y, [parts[i] for i in pos], batch_size, epochs,
-            seeds=[seeds[i] for i in pos], n_steps=int(caps[b]))
-        out.append((b, pos, xb, yb, mask))
-    return out
 
 
 # jitted eval fns, cached per Net.  Weak keys: an id()-keyed dict could hand

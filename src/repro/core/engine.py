@@ -9,10 +9,11 @@ compose:
                       that advances the host rng, so completed rounds can
                       be replayed draw-for-draw on resume);
   ``train_clients``   train every prototype group's clients in ONE jitted
-                      vmap-over-clients scan (``client.make_batched_local_
-                      update``) — batches stacked to [K_g, n_steps, B, ...],
-                      FedProx / quantize / DP inside the jit, optionally
-                      the client axis sharded over a device mesh;
+                      program (``client.make_batched_local_update``), one
+                      client after another, each for its own step count —
+                      batches stacked to [K_g, n_steps, B, ...], FedProx /
+                      quantize / DP inside the jit, optionally the client
+                      axis sharded over a device mesh;
   ``aggregate``       optional drop-worst filter + dispatch of the stacks
                       to the configured :class:`ServerStrategy`
                       (``core/strategies.py`` registry) -> new globals;
@@ -21,20 +22,16 @@ compose:
 Batch building (``build_round_batches``) is split out of ``train_clients``
 because it is a pure host-side function of ``(round, cohort)`` — the
 async-pipelined driver prefetches it rounds ahead without touching the
-trajectory.  Clients with fewer local steps than the padded scan length
-are masked, so each trajectory matches the sequential reference path
-exactly; scan lengths and client-axis sizes are fixed per run, so the
-compile count stays bounded for the whole run instead of one program per
-client per distinct shape.
+trajectory.  Batch tensors are padded to a run-fixed step capacity and
+client count, with a prefix step mask; the device runs only each client's
+masked-in steps, so each trajectory matches the sequential reference path
+and the compile count stays bounded for the whole run instead of one
+program per client per distinct shape.
 
-``FLConfig.bucketing`` (docs/bucketing.md) splits each prototype group
-into a small fixed set of step-count buckets, one cached ``vmap(scan)``
-per (prototype, bucket): on skewed Dirichlet splits this removes most of
-the masked no-op padding steps without changing any trajectory —
-bucketing only regroups the vmap axis, the per-client math is identical.
-The same fixed per-bucket client capacities, padded up to mesh
+The run-fixed per-prototype client capacities, padded up to mesh
 divisibility, are what let HETEROGENEOUS cohorts shard their client axis
-over a device mesh (``attach_mesh`` / the ``multihost`` driver).
+over a device mesh (``attach_mesh`` / the ``multihost`` driver; see
+docs/bucketing.md).
 
 :func:`run_rounds` keeps the historic flat API: it builds a
 :class:`RoundEngine` and hands it to a driver from the registry
@@ -53,11 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import feddf as feddf_mod
-from repro.core.client import (assign_buckets, bucket_capacities,
-                               build_bucketed_batches, evaluate,
+from repro.core.client import (REAL_STEPS, build_batched_batches, evaluate,
                                make_batched_local_update, n_local_steps)
-from repro.common.options import BUCKET_KINDS
-from repro.common.pytree import tree_cat, tree_isfinite, tree_take
+from repro.common.pytree import tree_isfinite, tree_take
 from repro.core.dropworst import drop_worst_stacked
 from repro.core.nets import Net
 from repro.core.strategies import GroupRound, RoundContext, get_strategy
@@ -91,22 +86,6 @@ _UNSET = object()
 
 
 @dataclasses.dataclass
-class BucketConfig:
-    """Step-count bucketing of the client axis (docs/bucketing.md).
-
-    ``kind``: ``none`` (pad every client of a group to the group maximum —
-    the historic path), ``pow2`` (power-of-two scan capacities) or
-    ``quantile`` (capacities at step-count quantiles).  ``max_buckets``
-    bounds the compile count: per run the engine compiles at most
-    ``buckets x prototypes`` client-update programs
-    (``core.client.CLIENT_COMPILES`` pins this in tests).  Bucketing
-    never changes a trajectory — it only regroups the vmap axis."""
-
-    kind: str = "none"        # none | pow2 | quantile
-    max_buckets: int = 4
-
-
-@dataclasses.dataclass
 class FLConfig:
     rounds: int = 20
     client_fraction: float = 0.4  # C
@@ -128,8 +107,6 @@ class FLConfig:
     # client-level DP on uploads (paper §3 privacy extension; core/privacy.py)
     dp_clip: Optional[float] = None
     dp_noise_multiplier: float = 0.0
-    # step-count bucketing of the client axis (docs/bucketing.md)
-    bucketing: BucketConfig = dataclasses.field(default_factory=BucketConfig)
     # population / traffic / sampler axis (docs/population.md); the
     # defaults reproduce the classic fixed-roster uniform draw bit-for-bit
     population: PopulationConfig = dataclasses.field(
@@ -218,37 +195,26 @@ def _make_opt(cfg: FLConfig) -> Optimizer:
 
 
 @dataclasses.dataclass
-class BucketBatch:
-    """One (prototype, step-bucket)'s stacked scan inputs.  With bucketing
-    disabled a group has exactly one of these, padded to the group-wide
-    maximum — the historic layout."""
+class RoundBatches:
+    """One prototype group's host-built round inputs (pure function of
+    ``(round, cohort)`` — prefetchable), padded to the run-fixed client
+    capacity and scan length."""
 
-    pos: np.ndarray              # positions into RoundBatches.ks
-    xb: np.ndarray               # [cap_clients, cap_steps, B, ...]
-    yb: np.ndarray               # [cap_clients, cap_steps, B]
-    step_mask: np.ndarray        # [cap_clients, cap_steps]
+    ks: List[int]                # active client ids of this group
+    xb: np.ndarray               # [cap_clients, steps_cap, B, ...]
+    yb: np.ndarray               # [cap_clients, steps_cap, B]
+    step_mask: np.ndarray        # [cap_clients, steps_cap], prefix masks
     dp_keys: np.ndarray          # [cap_clients, 2]
     k_real: int                  # un-padded client count
-    cap_steps: int               # the bucket's fixed scan length
+    weights: np.ndarray          # [k_real] local dataset sizes, in ks order
+    # accounting (docs/bucketing.md):
+    real_steps: int              # unmasked client-steps this group runs
+    padded_slots: int            # cap_clients * steps_cap: batch slots
+                                 # built on the host (only real_steps run)
 
     @property
     def cap_clients(self) -> int:
         return int(self.xb.shape[0])
-
-
-@dataclasses.dataclass
-class RoundBatches:
-    """One prototype group's host-built round inputs (pure function of
-    ``(round, cohort)`` — prefetchable), split over the run-fixed step
-    buckets."""
-
-    ks: List[int]                # active client ids of this group
-    buckets: List[BucketBatch]
-    k_real: int                  # un-padded client count over all buckets
-    weights: np.ndarray          # [k_real] local dataset sizes, in ks order
-    # padding-waste accounting (benchmarks/round_engine_bench.py):
-    real_steps: int              # unmasked client-steps this group runs
-    padded_slots: int            # sum of cap_clients * cap_steps over buckets
 
 
 class RoundEngine:
@@ -288,21 +254,17 @@ class RoundEngine:
         self.mesh = mesh
         self.client_axis = client_axis
 
-        if cfg.bucketing.kind not in BUCKET_KINDS:
-            raise ValueError(
-                f"bucketing.kind must be one of {BUCKET_KINDS}, got "
-                f"{cfg.bucketing.kind!r}")
         self.strategy = get_strategy(cfg.strategy)
         self.n_clients = len(parts)
         self.n_active = max(1, int(round(cfg.client_fraction
                                          * self.n_clients)))
         self.n_proto = len(nets)
-        # fixed scan lengths AND fixed client-axis sizes per (prototype,
-        # step-bucket) -> a bounded compile count for the whole run (group
-        # sizes vary round to round in the heterogeneous case; padded
-        # clients get an all-False step mask and are sliced off afterwards).
+        # fixed scan lengths AND fixed client-axis sizes per prototype ->
+        # one compile per prototype for the whole run (group sizes vary
+        # round to round in the heterogeneous case; padded clients get an
+        # all-False step mask, run no step and are sliced off afterwards).
         # All of this is a pure function of the STATIC per-client dataset
-        # sizes, so the bucket structure never changes across rounds.
+        # sizes, so it never changes across rounds.
         self.client_steps = [
             n_local_steps(len(parts[k]), cfg.local_batch_size,
                           cfg.local_epochs)
@@ -315,19 +277,6 @@ class RoundEngine:
                         for p in range(self.n_proto)]
         self.k_cap = [min(self.n_active, c) if c else 1
                       for c in proto_counts]
-        # per-prototype bucket capacities + bucket population counts (the
-        # static client -> bucket assignment itself is recomputed from the
-        # same step counts inside build_bucketed_batches each round)
-        self.bucket_caps, self._bucket_counts = [], []
-        for p in range(self.n_proto):
-            steps_p = [self.client_steps[k] for k in range(self.n_clients)
-                       if self.client_proto[k] == p]
-            caps = bucket_capacities(steps_p or [1], cfg.bucketing.kind,
-                                     cfg.bucketing.max_buckets)
-            self.bucket_caps.append(caps)
-            self._bucket_counts.append(np.bincount(
-                assign_buckets(steps_p, caps) if steps_p else [],
-                minlength=len(caps)))
         self.batch_seed_mult = 99991 if heterogeneous else 100_003
         # population / scheduler seam (docs/population.md): cohort draws
         # go through a pluggable sampler bound to run-fixed population
@@ -336,18 +285,6 @@ class RoundEngine:
         from repro.population.scheduler import SamplerContext, make_sampler
         cfg.population.validate()
         self.population_size = int(cfg.population.size or self.n_clients)
-        self._part_bucket = np.zeros(self.n_clients, np.int64)
-        for p in range(self.n_proto):
-            ks = [k for k in range(self.n_clients)
-                  if self.client_proto[k] == p]
-            if ks:
-                self._part_bucket[ks] = assign_buckets(
-                    [self.client_steps[k] for k in ks], self.bucket_caps[p])
-        # meshless per-(proto, bucket) client caps: the capacity_aware
-        # sampler's fill guide (matches _bucket_client_cap without a mesh)
-        self._sampler_caps = [
-            [min(self.k_cap[p], int(c)) or 1 for c in self._bucket_counts[p]]
-            for p in range(self.n_proto)]
         pop_part = np.arange(self.population_size,
                              dtype=np.int64) % self.n_clients
         self.sampler = make_sampler(cfg.population.sampler).bind(
@@ -355,8 +292,10 @@ class RoundEngine:
                 n_clients=self.population_size,
                 n_partitions=self.n_clients,
                 proto=np.asarray(self.client_proto, np.int64)[pop_part],
-                bucket=self._part_bucket[pop_part],
-                bucket_client_caps=self._sampler_caps))
+                # one cell per prototype, filled to its meshless client
+                # capacity (the capacity_aware sampler's guide)
+                bucket=np.zeros(self.population_size, np.int64),
+                bucket_client_caps=[[k] for k in self.k_cap]))
         self._population = None  # built lazily by population()
         cfg.faults.validate()
         self._fault_model = None  # built lazily by fault_model()
@@ -376,11 +315,11 @@ class RoundEngine:
         """Fail loudly where BOTH mesh paths (constructor-supplied and
         driver-attached) converge, instead of deep inside shard_map.
 
-        Heterogeneous and bucketed runs pad every (prototype, bucket)
-        client capacity up to mesh divisibility instead (the padded lanes
-        carry all-False step masks and are sliced off), so only the
-        historic unbucketed homogeneous path keeps the strict check."""
-        if self.heterogeneous or self.cfg.bucketing.kind != "none":
+        Heterogeneous runs pad every prototype's client capacity up to
+        mesh divisibility instead (the padded slots carry all-False step
+        masks, run no step and are sliced off), so only the homogeneous
+        path keeps the strict check."""
+        if self.heterogeneous:
             return
         axis = mesh.shape[client_axis]
         bad = [k for k in self.k_cap if k % axis]
@@ -391,14 +330,13 @@ class RoundEngine:
                 f"client_fraction/n_clients so K is a multiple of the "
                 f"device count")
 
-    def _bucket_client_cap(self, p: int, b: int) -> int:
-        """Run-fixed client-axis size of (prototype p, bucket b): no round
-        can activate more of the bucket's clients than exist, so this
-        never retraces; with a mesh it is rounded up to axis divisibility
-        (except on the strictly-validated unbucketed homogeneous path)."""
-        cap = min(self.k_cap[p], int(self._bucket_counts[p][b])) or 1
-        if self.mesh is not None and (self.heterogeneous
-                                      or self.cfg.bucketing.kind != "none"):
+    def _client_cap(self, p: int) -> int:
+        """Run-fixed client-axis size of prototype p: no round can
+        activate more of its clients than ``k_cap``, so this never
+        retraces; with a mesh it is rounded up to axis divisibility
+        (except on the strictly-validated homogeneous path)."""
+        cap = self.k_cap[p]
+        if self.mesh is not None and self.heterogeneous:
             axis = self.mesh.shape[self.client_axis]
             cap = -(-cap // axis) * axis
         return cap
@@ -408,8 +346,8 @@ class RoundEngine:
     def attach_mesh(self, mesh, client_axis: str = "data") -> None:
         """Shard the client axis of local training over ``mesh`` (multihost
         driver seam).  Must run before the first ``train_clients`` call.
-        Heterogeneous / bucketed engines pad their run-fixed per-bucket
-        client capacities up to mesh divisibility, so they shard too."""
+        Heterogeneous engines pad their run-fixed per-prototype client
+        capacities up to mesh divisibility, so they shard too."""
         if self._updates is not None:
             raise RuntimeError("attach_mesh must be called before the "
                                "first train_clients call")
@@ -474,7 +412,7 @@ class RoundEngine:
                 partition_sizes=[len(p) for p in self.parts],
                 client_steps=self.client_steps,
                 client_proto=self.client_proto,
-                client_bucket=self._part_bucket,
+                client_bucket=np.zeros(self.n_clients, np.int64),
                 n_active=self.n_active, sampler=self.sampler,
                 faults=self.cfg.faults)
         return self._population
@@ -658,75 +596,60 @@ class RoundEngine:
             if not ks:
                 out.append(None)
                 continue
-            caps = self.bucket_caps[p]
             seeds = [cfg.seed * self.batch_seed_mult + t * 131 + k
                      for k in ks]
-            buckets: List[BucketBatch] = []
-            real_steps = padded_slots = 0
-            for b, pos, xb, yb, step_mask in build_bucketed_batches(
-                    self.train.x, self.train.y,
-                    [self.parts[k] for k in ks],
-                    cfg.local_batch_size, cfg.local_epochs, seeds, caps):
-                kb = [ks[i] for i in pos]
-                if cfg.dp_clip is not None:
-                    dp_keys = np.stack([
-                        np.asarray(jax.random.PRNGKey(
-                            cfg.seed * 7919 + t * 131 + k)) for k in kb])
-                else:
-                    dp_keys = np.zeros((len(kb), 2), np.uint32)
-                k_real = len(kb)
-                cap_k = self._bucket_client_cap(p, b)
-                if k_real < cap_k:  # pad the client axis to fixed size
-                    pad = cap_k - k_real
-                    zpad = lambda a: np.concatenate(
-                        [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
-                    xb, yb, step_mask, dp_keys = (
-                        zpad(xb), zpad(yb), zpad(step_mask), zpad(dp_keys))
-                real_steps += int(step_mask.sum())
-                padded_slots += cap_k * caps[b]
-                buckets.append(BucketBatch(
-                    pos=np.asarray(pos), xb=xb, yb=yb, step_mask=step_mask,
-                    dp_keys=dp_keys, k_real=k_real, cap_steps=caps[b]))
+            xb, yb, step_mask = build_batched_batches(
+                self.train.x, self.train.y, [self.parts[k] for k in ks],
+                cfg.local_batch_size, cfg.local_epochs, seeds,
+                n_steps=self.steps_cap[p])
+            if cfg.dp_clip is not None:
+                dp_keys = np.stack([
+                    np.asarray(jax.random.PRNGKey(
+                        cfg.seed * 7919 + t * 131 + k)) for k in ks])
+            else:
+                dp_keys = np.zeros((len(ks), 2), np.uint32)
+            cap_k = self._client_cap(p)
+            if len(ks) < cap_k:  # pad the client axis to fixed size
+                pad = cap_k - len(ks)
+                zpad = lambda a: np.concatenate(
+                    [a, np.zeros((pad,) + a.shape[1:], a.dtype)])
+                xb, yb, step_mask, dp_keys = (
+                    zpad(xb), zpad(yb), zpad(step_mask), zpad(dp_keys))
             weights = np.array([float(len(self.parts[k])) for k in ks])
-            out.append(RoundBatches(ks=ks, buckets=buckets, k_real=len(ks),
-                                    weights=weights, real_steps=real_steps,
-                                    padded_slots=padded_slots))
+            out.append(RoundBatches(
+                ks=ks, xb=xb, yb=yb, step_mask=step_mask, dp_keys=dp_keys,
+                k_real=len(ks), weights=weights,
+                real_steps=int(step_mask.sum()),
+                padded_slots=cap_k * self.steps_cap[p]))
         return out
 
-    @_spanned("train_clients")
     def train_clients(self, t: int, globals_: List[dict],
                       batches: List[Optional[RoundBatches]]
                       ) -> List[GroupRound]:
         """Run every group's batched local update from ``globals_``.  The
         async driver may pass globals one fusion STALER than sync would
-        (bounded staleness; see docs/drivers.md).
-
-        Per-bucket stacks are re-joined IN THE GROUP'S ORIGINAL CLIENT
-        ORDER, so aggregation consumes bit-identical inputs whether or
-        not bucketing regrouped the vmap axis."""
-        groups: List[GroupRound] = []
-        for p, rb in enumerate(batches):
-            if rb is None:
-                groups.append(GroupRound(self.nets[p], globals_[p], None,
-                                         np.zeros(0)))
-                continue
-            pieces = []
-            for bb in rb.buckets:
-                stack = self.updates[p](globals_[p], jnp.asarray(bb.xb),
-                                        jnp.asarray(bb.yb), globals_[p],
-                                        jnp.asarray(bb.step_mask),
-                                        jnp.asarray(bb.dp_keys))
-                if bb.k_real < bb.cap_clients:
-                    stack = tree_take(stack, np.arange(bb.k_real))
-                pieces.append(stack)
-            stack = tree_cat(pieces)
-            pos = np.concatenate([bb.pos for bb in rb.buckets])
-            if not np.array_equal(pos, np.arange(rb.k_real)):
-                inv = np.empty_like(pos)
-                inv[pos] = np.arange(len(pos))
-                stack = tree_take(stack, inv)
-            groups.append(GroupRound(self.nets[p], globals_[p], stack,
-                                     rb.weights))
+        (bounded staleness; see docs/drivers.md).  The span and the
+        ``core.client.real_steps`` counter carry the client-steps the
+        round's cohort holds."""
+        with _trace.span("train_clients", round=int(t)) as sp:
+            groups: List[GroupRound] = []
+            real_steps = 0
+            for p, rb in enumerate(batches):
+                if rb is None:
+                    groups.append(GroupRound(self.nets[p], globals_[p],
+                                             None, np.zeros(0)))
+                    continue
+                stack = self.updates[p](globals_[p], jnp.asarray(rb.xb),
+                                        jnp.asarray(rb.yb), globals_[p],
+                                        jnp.asarray(rb.step_mask),
+                                        jnp.asarray(rb.dp_keys))
+                if rb.k_real < rb.cap_clients:
+                    stack = tree_take(stack, np.arange(rb.k_real))
+                real_steps += rb.real_steps
+                groups.append(GroupRound(self.nets[p], globals_[p], stack,
+                                         rb.weights))
+            sp.annotate(real_steps=real_steps)
+            REAL_STEPS.add(real_steps)
         return groups
 
     @_spanned("aggregate")
@@ -824,9 +747,9 @@ def run_rounds(
 ) -> Tuple[List[FLResult], List[dict], Optional[int]]:
     """The shared round loop.  Returns (per-prototype results, final
     globals, rounds_to_target).  ``mesh`` shards the client axis of local
-    training over ``client_axis``; heterogeneous / bucketed runs pad
-    their run-fixed per-bucket client capacities up to mesh divisibility,
-    the unbucketed homogeneous path requires the active cohort size to
+    training over ``client_axis``; heterogeneous runs pad their run-fixed
+    per-prototype client capacities up to mesh divisibility, the
+    homogeneous path requires the active cohort size to
     divide the axis size (validated loudly).  Homogeneous
     callers pass one net and ``client_proto`` all zeros; ``log_fn``
     receives ``RoundLog`` (homogeneous) or ``(group, RoundLog)``

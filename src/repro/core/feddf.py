@@ -765,7 +765,7 @@ def feddf_fuse_heterogeneous_stacked(
     size; the sizes are bucketed into run-fixed padded capacities
     (``fusion.distill_bucket``: ``none`` pads every group to the largest
     size, ``pow2``/``quantile`` give small students intermediate
-    capacities) exactly like the client axis in docs/bucketing.md.
+    capacities; docs/bucketing.md).
     Trajectories are identical across kinds — padded rows never reach
     the loss.
     """

@@ -1,7 +1,7 @@
 """Client pods: the training half of the distributed runtime.
 
 A :class:`ClientPodRunner` serves TRAIN frames against its engine — it
-decodes the round globals off the wire, runs the bucketed ``vmap(scan)``
+decodes the round globals off the wire, runs the batched client
 training (``engine.build_round_batches`` + ``engine.train_clients``) for
 exactly the client ids the frame names, and replies with one UPLOAD
 frame holding a codec-encoded blob per client.  It is transport-agnostic
@@ -13,7 +13,7 @@ Client k homes on pod ``k % n_pods`` (:func:`shard_clients`), but homing
 is only a routing default — re-dispatch after a pod death sends the same
 ids elsewhere and the trajectory is unchanged, because per-client
 training is a deterministic function of (round, client, globals),
-independent of grouping (the PR 5 bucketing invariant).
+independent of grouping.
 
 ``python -m repro.dist.pods`` is the TCP subprocess entry: it rebuilds
 an engine from a serialized ExperimentSpec (identical by construction to

@@ -6,11 +6,11 @@ Two entry points at two scales:
 * :class:`MultiHostDriver` — the experiment path.  Attaches a 1-D client
   mesh (``launch/mesh.py:make_client_mesh``) to the
   :class:`~repro.core.engine.RoundEngine` so the K active clients of the
-  batched vmap-over-clients update train data-parallel across devices
-  (``shard_map``).  Unbucketed homogeneous runs require K to divide the
-  device count; heterogeneous and bucketed runs pad their run-fixed
-  per-(prototype, bucket) client capacities up to mesh divisibility
-  instead (padded lanes carry all-False step masks and are sliced off),
+  batched client update train data-parallel across devices
+  (``shard_map``).  Homogeneous runs require K to divide the device
+  count; heterogeneous runs pad their run-fixed per-prototype client
+  capacities up to mesh divisibility instead (padded slots carry
+  all-False step masks, run no step and are sliced off),
   so skewed hetero cohorts shard too — see docs/bucketing.md.  Runs on
   real accelerators or a
   ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` simulated host
@@ -34,8 +34,8 @@ from repro.drivers.sync import SyncDriver
 
 @register_driver("multihost")
 class MultiHostDriver(SyncDriver):
-    """Sync driver over a client-sharded mesh.  Heterogeneous / bucketed
-    engines pad their run-fixed per-bucket client capacities up to mesh
+    """Sync driver over a client-sharded mesh.  Heterogeneous engines
+    pad their run-fixed per-prototype client capacities up to mesh
     divisibility (``RoundEngine.attach_mesh``), so they shard exactly
     like homogeneous cohorts."""
 

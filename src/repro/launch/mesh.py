@@ -78,8 +78,8 @@ def make_host_mesh(hosts: int | None = None,
 def make_client_mesh(n: int | None = None) -> jax.sharding.Mesh:
     """1-D ("data",) mesh for the federated round engine: the stacked
     client axis of ``make_batched_local_update`` shards over it, so K
-    active clients train data-parallel.  Unbucketed homogeneous runs need
-    K to divide ``n``; heterogeneous / bucketed runs pad their client
+    active clients train data-parallel.  Homogeneous runs need K to
+    divide ``n``; heterogeneous runs pad their client
     capacities up to divisibility (docs/bucketing.md).  Defaults to every
     visible device."""
     n = n or len(jax.devices())

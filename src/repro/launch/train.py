@@ -27,9 +27,6 @@ over every visible device/host — heterogeneous cohorts included), or
 ``distributed`` (fusion pod + client pods behind the versioned wire
 protocol — ``--transport``, ``--wire-codec``, ``--heartbeat-s``,
 ``--upload-deadline-s``; docs/distributed.md).
-``--bucket-by pow2|quantile`` buckets clients by local-step count so
-skewed non-IID cohorts stop scanning padded no-op steps
-(docs/bucketing.md; trajectories identical to ``none``).
 """
 from __future__ import annotations
 
@@ -38,7 +35,7 @@ import json
 import os
 import time
 
-from repro.api import (BucketSpec, CohortSpec, DistSpec, DriverSpec,
+from repro.api import (CohortSpec, DistSpec, DriverSpec,
                        Experiment, ExperimentSpec, FaultSpec, FusionSpec,
                        ModelSpec, ObsSpec, PartitionSpec, PopulationSpec,
                        PrivacySpec, ShardingSpec, SourceSpec, StrategySpec,
@@ -100,8 +97,6 @@ def spec_from_args(args: argparse.Namespace) -> ExperimentSpec:
         sharding=ShardingSpec(shard_clients=args.shard_clients),
         driver=DriverSpec(kind=args.driver, staleness=args.staleness,
                           prefetch=args.prefetch),
-        bucket=BucketSpec(kind=args.bucket_by,
-                          max_buckets=args.max_buckets),
         population=PopulationSpec(
             size=args.population_size, sampler=args.sampler,
             buffer_size=args.buffer_size,
@@ -199,15 +194,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "async_pipelined (overlap round t+1 client "
                          "training with round t fusion) | multihost "
                          "(client axis sharded over all devices)")
-    ap.add_argument("--bucket-by", default="none",
-                    choices=["none", "pow2", "quantile"],
-                    help="bucket clients by local-step count so skewed "
-                         "cohorts stop scanning padded no-op steps "
-                         "(docs/bucketing.md); trajectories are identical "
-                         "to --bucket-by none")
-    ap.add_argument("--max-buckets", type=int, default=4,
-                    help="cap on step buckets per prototype (bounds the "
-                         "compile count at buckets x prototypes)")
     ap.add_argument("--bank-dtype", default="float32",
                     choices=list(BANK_DTYPES),
                     help="teacher-logit-bank storage dtype "
@@ -265,8 +251,8 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=available_samplers(),
                     help="cohort sampler (docs/population.md): uniform "
                          "(historic draw, bit-identical) | capacity_aware "
-                         "(fills PR5 step-buckets evenly to cut padding "
-                         "waste) | prioritized (O(log N) sum-tree, stale "
+                         "(fills each prototype's client capacity "
+                         "first) | prioritized (O(log N) sum-tree, stale "
                          "clients bubble up)")
     ap.add_argument("--buffer-size", type=int, default=None,
                     help="buffered_async: aggregate every M buffered "
